@@ -20,6 +20,7 @@ import argparse
 import time
 
 from .common import emit
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -28,6 +29,7 @@ def main() -> None:
                     help="smaller sizes / fewer sweeps")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     q = args.quick
 
     print("section,name,value,unit,notes", flush=True)

@@ -54,7 +54,10 @@ def run(device_counts=(1, 2, 4, 8)):
         proc = subprocess.run(
             [sys.executable, "-c", _CHILD % (n, n)],
             capture_output=True, text=True, cwd=os.getcwd(),
-            env={**os.environ, "PYTHONPATH": "src"}, timeout=600)
+            # simulated host devices: the children never ask for an
+            # accelerator, so they cannot compete for the chip
+            env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"},
+            timeout=600)
         if proc.returncode != 0:
             emit("scaling", f"devices_{n}", "ERROR", "s/sweep",
                  proc.stderr.strip().splitlines()[-1][:100]

@@ -38,6 +38,7 @@ from repro.obs import (Histogram, clock, latency_buckets,
                        percentile_summary)
 
 from .common import emit
+from repro.launch.compile_cache import enable_compile_cache
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..",
                            "results", "serving")
@@ -193,6 +194,7 @@ def main() -> None:
     ap.add_argument("--out", default=None,
                     help="JSON output path (default results/serving/)")
     args = ap.parse_args()
+    enable_compile_cache()
     print("section,name,value,unit,notes", flush=True)
     run(quick=args.quick, out=args.out)
 

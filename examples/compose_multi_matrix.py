@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.core import AdaptiveGaussian, ModelBuilder, PredictSession, \
     from_coo
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def make_data(seed, n_compounds, n_targets, n_cells, n_features, rank,
@@ -65,6 +66,7 @@ def main():
     ap.add_argument("--save-dir", default=None,
                     help="posterior-sample store (default: a tempdir)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.quick:
         args.compounds, args.targets, args.cells = 96, 24, 12
         args.features, args.burnin, args.nsamples = 12, 15, 15

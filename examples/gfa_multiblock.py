@@ -12,6 +12,7 @@ import argparse
 import numpy as np
 
 from repro.core import GFASession
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -21,6 +22,7 @@ def main():
     ap.add_argument("--n", type=int, default=200,
                     help="shared sample count")
     args = ap.parse_args()
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     N = args.n
     dims = (50, 40, 30)

@@ -10,6 +10,7 @@ import numpy as np
 
 from repro.core import AdaptiveGaussian, TrainSession
 from repro.data.synthetic import chembl_like
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def fit(R, test, F, tag):
@@ -24,6 +25,7 @@ def fit(R, test, F, tag):
 
 
 def main():
+    enable_compile_cache()
     R, test, F = chembl_like(3, 1500, 120, density=0.04, rank=8,
                              noise=0.2, n_features=64,
                              feature_noise=0.25)

@@ -14,6 +14,7 @@ import argparse
 
 from repro.core import AdaptiveGaussian, ModelBuilder
 from repro.data.synthetic import chembl_like
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--proteins", type=int, default=200)
     ap.add_argument("--density", type=float, default=0.05)
     args = ap.parse_args()
+    enable_compile_cache()
 
     print("generating ChEMBL-like activity matrix "
           f"({args.compounds} compounds x {args.proteins} proteins)...")

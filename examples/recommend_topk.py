@@ -24,6 +24,7 @@ import numpy as np
 from repro.core import (AdaptiveGaussian, ModelBuilder, PredictSession)
 from repro.core.sparse import from_coo
 from repro.launch.serve import RecommendServer
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def make_activity_data(rng, n_compounds, n_targets, n_feat=12, rank=4):
@@ -41,6 +42,7 @@ def main():
     ap.add_argument("--quick", action="store_true",
                     help="smaller problem / fewer samples")
     args = ap.parse_args()
+    enable_compile_cache()
     n_c, n_t, burnin, nsamples = \
         (60, 24, 8, 8) if args.quick else (300, 64, 40, 40)
 

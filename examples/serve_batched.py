@@ -12,9 +12,11 @@ import jax
 from repro.configs import get_smoke
 from repro.launch.serve import BatchedServer
 from repro.models import init_model
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     cfg = get_smoke("qwen3_4b")
     print(f"serving {cfg.name}: {cfg.n_layers}L d={cfg.d_model}")
     params = init_model(jax.random.PRNGKey(0), cfg)

@@ -17,6 +17,7 @@ import argparse
 from repro.configs import ARCHS, get_config, get_smoke
 from repro.launch.train import train
 from repro.optim import AdamWConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None,
                     help="enable checkpoint/auto-resume")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full else get_smoke(args.arch)
     print(f"arch={cfg.name}  layers={cfg.n_layers}  d={cfg.d_model}  "

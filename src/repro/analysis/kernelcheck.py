@@ -394,7 +394,9 @@ def _block_index(spec, gp) -> Tuple[int, ...]:
 
 
 def _spec_line(spec, default: int) -> int:
-    code = getattr(spec.index_map, "__code__", None)
+    # JAX wraps a BlockSpec's index map; the user's function (and its
+    # source line) sits behind ``__wrapped__``
+    code = getattr(inspect.unwrap(spec.index_map), "__code__", None)
     return code.co_firstlineno if code is not None else default
 
 

@@ -1,159 +1,62 @@
-"""Version-portable JAX shims, feature-detected once at import.
+"""The one module that touches JAX's sharding API surface.
 
-JAX moved several sharding APIs between 0.4.x and 0.5+/0.6+:
-
-* ``jax.make_mesh`` grew an ``axis_types=`` kwarg (and
-  ``jax.sharding.AxisType`` only exists where it did);
-* ``jax.sharding.AbstractMesh`` changed signature from a tuple of
-  ``(name, size)`` pairs to ``(axis_sizes, axis_names)``;
-* ``jax.sharding.get_abstract_mesh`` was promoted out of
-  ``jax._src.mesh`` (where older versions return an *empty* mesh
-  instead of ``None``);
-* ``shard_map`` moved from ``jax.experimental.shard_map`` (kwarg
-  ``check_rep``) to ``jax.shard_map`` (kwarg ``check_vma``);
-* ``jax.set_mesh`` replaced the legacy ``with mesh:`` context;
-* ``compiled.cost_analysis()`` returned a one-element ``list`` of dicts
-  on 0.4.x and returns a plain ``dict`` on newer releases.
-
-Every capability is detected by probing the API surface — never by
-comparing version strings — so intermediate releases that carry only
-some of the changes still resolve correctly.  All modules under
-``repro`` go through these wrappers; nothing else may touch the moved
-names directly (enforced by the tier-1 suite staying green on both the
-pinned and the latest JAX in CI).
+The rest of ``repro`` builds meshes, abstract meshes and ``shard_map``
+programs through these wrappers, so a JAX upgrade that moves one of
+these names breaks exactly one module (enforced by the
+``repro.analysis`` invariant linter).  Written against the installed
+JAX 0.9 API: one call per shim.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AbstractMesh, AxisType
 
-# --------------------------------------------------------------------------
-# axis types
-# --------------------------------------------------------------------------
-
-try:  # newer JAX: jax.sharding.AxisType.{Auto,Explicit,Manual}
-    from jax.sharding import AxisType as _AxisType
-    AXIS_TYPE_AUTO: Any = _AxisType.Auto
-except ImportError:  # 0.4.x: no axis types — meshes are implicitly Auto
-    AXIS_TYPE_AUTO = None
-
-
-# --------------------------------------------------------------------------
-# mesh construction
-# --------------------------------------------------------------------------
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
               devices=None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API supports it."""
-    shape = tuple(shape)
+    """``jax.make_mesh`` with Auto axis types on every axis."""
     axes = tuple(axes)
-    kwargs: Dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if AXIS_TYPE_AUTO is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(AXIS_TYPE_AUTO,) * len(axes),
-                                 **kwargs)
-        except TypeError:  # AxisType exists but make_mesh predates kwarg
-            pass
-    return jax.make_mesh(shape, axes, **kwargs)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def abstract_mesh(shape: Sequence[int],
-                  axes: Sequence[str]) -> "jax.sharding.AbstractMesh":
-    """Device-free mesh for spec/tracing logic, both constructor eras."""
-    from jax.sharding import AbstractMesh
-    shape = tuple(shape)
-    axes = tuple(axes)
-    try:  # newer JAX: AbstractMesh(axis_sizes, axis_names)
-        return AbstractMesh(shape, axes)
-    except TypeError:  # 0.4.x: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(axes, shape)))
+                  axes: Sequence[str]) -> AbstractMesh:
+    """Device-free mesh for spec/tracing logic."""
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def get_abstract_mesh():
-    """The abstract mesh of the current sharding context, or ``None``.
-
-    Normalizes the empty-mesh sentinel older JAX returns outside any
-    mesh context to ``None`` so callers only branch one way.
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        try:
-            from jax._src.mesh import get_abstract_mesh as fn
-        except ImportError:
-            return None
-    try:
-        mesh = fn()
-    except Exception:  # pragma: no cover — defensive against API drift
-        return None
-    if mesh is None or not getattr(mesh, "axis_names", None):
+    """The abstract mesh of the current sharding context, or ``None``
+    outside any mesh context (JAX returns an empty mesh there)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or not mesh.axis_names:
         return None
     return mesh
 
 
 def use_mesh(mesh: jax.sharding.Mesh):
-    """Context manager activating ``mesh`` for the enclosed region.
+    """Context manager activating ``mesh`` for the enclosed region."""
+    return jax.set_mesh(mesh)
 
-    Prefers the forms that are documented context managers
-    (``jax.sharding.use_mesh``, then ``jax.set_mesh``); on 0.4.x falls
-    back to the legacy ``with mesh:`` global-mesh context.  Returns a
-    nullcontext as last resort — our jit paths pass explicit
-    NamedShardings and never rely on the ambient mesh alone.
-    """
-    for fn in (getattr(jax.sharding, "use_mesh", None),
-               getattr(jax, "set_mesh", None)):
-        if fn is not None:
-            ctx = fn(mesh)
-            if hasattr(ctx, "__enter__"):
-                return ctx
-    if hasattr(mesh, "__enter__"):  # legacy global-mesh context
-        return mesh
-    return contextlib.nullcontext()
-
-
-# --------------------------------------------------------------------------
-# shard_map
-# --------------------------------------------------------------------------
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``shard_map`` across its import-location / check-kwarg renames."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        try:
-            return fn(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=check)
-        except TypeError:  # jax.shard_map exists but still says check_rep
-            return fn(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
+    """``jax.shard_map``; ``check`` maps to its ``check_vma``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
-
-# --------------------------------------------------------------------------
-# compiled-artifact analysis
-# --------------------------------------------------------------------------
 
 def cost_analysis(compiled) -> Dict[str, Any]:
-    """``compiled.cost_analysis()`` normalized to one flat dict.
-
-    JAX 0.4.x returns a one-element list of per-program dicts; newer
-    JAX returns the dict itself.  Always returns a (possibly empty)
-    dict, never a list.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """``compiled.cost_analysis()`` as a plain dict (empty when the
+    backend reports nothing)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def mesh_axis_sizes(mesh, axes: Optional[Tuple[str, ...]] = None
                     ) -> Tuple[int, ...]:
     """Sizes of ``axes`` (default: all axes) on a Mesh or AbstractMesh."""
     names = tuple(mesh.axis_names) if axes is None else tuple(axes)
-    shape = mesh.shape  # dict-like on every supported version
-    return tuple(int(shape[a]) for a in names)
+    return tuple(int(mesh.shape[a]) for a in names)

@@ -63,6 +63,58 @@ def chembl_like(seed: int, n_compounds: int = 2000, n_proteins: int = 200,
     return mat, test, F
 
 
+def fixed_degree(seed: int, n_rows: int, n_cols: int, nnz_per_row: int,
+                 n_test_per_row: int = 8, rank: int = 8,
+                 noise: float = 0.3
+                 ) -> Tuple[SparseMatrix, Tuple, np.ndarray]:
+    """Planted low-rank data with exactly ``nnz_per_row`` observations
+    per row.
+
+    The columns split into ``nnz_per_row`` equal stripes and each row
+    observes one random column of every stripe.  Column degrees are
+    then binomial around ``n_rows * nnz_per_row / n_cols``, so neither
+    padded orientation is much wider than the mean degree.  (The
+    power-law rows of :func:`chembl_like` give the busiest row nearly
+    every column at scale, and padded CSR pads every row to it.)  Each
+    row also holds out ``n_test_per_row`` entries, in distinct stripes,
+    at columns it does not observe.  Values are a rank-``rank`` product
+    of unit variance plus Gaussian noise of std ``noise``.
+
+    Returns (train SparseMatrix, (i, j, v) test triplets,
+    (n_rows, nnz_per_row) observed column ids per row).
+    """
+    if n_cols % nnz_per_row:
+        raise ValueError(f"n_cols={n_cols} is not a multiple of "
+                         f"nnz_per_row={nnz_per_row}")
+    stripe = n_cols // nnz_per_row
+    if n_test_per_row > nnz_per_row or (n_test_per_row and stripe < 2):
+        raise ValueError(
+            f"no room for {n_test_per_row} held-out entries per row "
+            f"with {nnz_per_row} stripes of {stripe} columns")
+    rng = np.random.default_rng(seed)
+    scale = np.float32(rank ** -0.25)
+    U = rng.standard_normal((n_rows, rank), np.float32) * scale
+    V = rng.standard_normal((n_cols, rank), np.float32) * scale
+    offs = rng.integers(0, stripe, (n_rows, nnz_per_row))
+    cols = np.arange(nnz_per_row) * stripe + offs
+    held = np.argsort(rng.random((n_rows, nnz_per_row)),
+                      axis=1)[:, :n_test_per_row]
+    held_off = (np.take_along_axis(offs, held, axis=1)
+                + rng.integers(1, max(stripe, 2), held.shape)) % stripe
+    test_cols = held * stripe + held_off
+
+    def observe(i, j):
+        return (np.einsum("ek,ek->e", U[i], V[j]) + noise
+                * rng.standard_normal(len(i), np.float32))
+
+    i = np.repeat(np.arange(n_rows), nnz_per_row)
+    j = cols.ravel()
+    mat = from_coo(i, j, observe(i, j), (n_rows, n_cols))
+    ti = np.repeat(np.arange(n_rows), n_test_per_row)
+    tj = test_cols.ravel()
+    return mat, (ti, tj, observe(ti, tj)), cols
+
+
 class TokenStream:
     """Deterministic, seekable synthetic token stream for LM training.
 

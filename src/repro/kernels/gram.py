@@ -48,8 +48,13 @@ def _gram_kernel(vg_ref, val_ref, mask_ref, gram_ref, rhs_ref):
         vm, vg,
         dimension_numbers=(((1,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)
-    # (BR, K, BT) @ (BR, BT) -> (BR, K)
-    b = jnp.einsum("rtk,rt->rk", vg, w, preferred_element_type=jnp.float32)
+    # (BR, 1, BT) @ (BR, BT, K) -> (BR, K).  Mosaic lowers a batched
+    # matmul with a unit M dim; the (BR, BT) x (BR, BT, K) contraction
+    # over a non-minor rhs dim is refused by the TPU compiler.
+    b = jax.lax.dot_general(
+        w[:, None, :], vg,
+        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)[:, 0, :]
 
     @pl.when(t == 0)
     def _init():

@@ -1,10 +1,11 @@
 """Public kernel entry points.
 
 ``gram_and_rhs`` / ``sddmm`` / ``topk_score`` dispatch between the
-Pallas kernel (TPU target; ``interpret=True`` on CPU) and the pure-jnp
+Pallas kernel (compiled on TPU, interpreted on CPU) and the pure-jnp
 oracle, controlled by the ``use_pallas`` flag carried in the session
-config.  On this container (CPU-only) the default is the XLA path;
-tests exercise the Pallas path in interpret mode.
+config.  The default is the XLA path; the tests exercise the Pallas
+path in interpret mode on CPU, ``tests/test_tpu_compile.py`` compiles
+it for a described TPU v5e, and ``chip_smoke.py`` runs it on the chip.
 
 Kernel contracts
 ----------------
@@ -17,11 +18,10 @@ repro.analysis --kernels``; rule catalogue in
 supported shape envelope: the checker concretely enumerates each
 kernel's grid over exactly these configurations, so a new kernel, a
 new block size, or a bigger serving store belongs in a new
-:class:`KernelProbe` **first** — the CPU container only ever runs
-kernels in interpret mode, and the checker is what stands between a
-grid bug and its first real-TPU execution.  All wrapper padding goes
-through :func:`pad_to_blocks` so the bounds checker verifies a single
-padding path.
+:class:`KernelProbe` **first** — interpret mode on CPU does not check
+grids, and the checker is what stands between a grid bug and a TPU
+run.  All wrapper padding goes through :func:`pad_to_blocks` so the
+bounds checker verifies a single padding path.
 """
 from __future__ import annotations
 
@@ -38,7 +38,25 @@ from .gram import gram_pallas
 from .sddmm import sddmm_pallas
 from .topk_score import topk_score_pallas
 
-_ON_TPU = jax.default_backend() == "tpu"
+
+def _interpret(interpret: bool | None) -> bool:
+    """Whether a Pallas call runs in interpret mode.
+
+    An explicit ``interpret`` wins.  Otherwise it is decided at call
+    time from the backend: the TPU compiles the kernel, the CPU
+    interprets it, and any other backend raises instead of silently
+    running the interpreter on a device.
+    """
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"no Pallas path for backend {backend!r}: the kernels compile "
+        "for TPU and run in interpret mode on CPU only")
 
 # the serving hot loop calls this per service step; eager lax.map
 # dispatch costs more than the scoring itself (measured ~100x on the
@@ -80,8 +98,9 @@ def gram_and_rhs(vg: jnp.ndarray, val: jnp.ndarray, mask: jnp.ndarray,
     """
     if not use_pallas:
         return ref.gram_ref(vg, val, mask)
-    interpret = (not _ON_TPU) if interpret is None else interpret
-    br, bt = 8, 128
+    interpret = _interpret(interpret)
+    # a block as wide as T is always legal; wider rows tile by 128
+    br, bt = 8, min(128, vg.shape[1])
     R = vg.shape[0]
     vg_p = pad_to_blocks(vg, {0: br, 1: bt})
     val_p = pad_to_blocks(val, {0: br, 1: bt})
@@ -125,7 +144,10 @@ def topk_score(us: jnp.ndarray, v: jnp.ndarray, k: int, *,
                 f"exclude shape {excl.shape} != (B, N) = {(B, N)}")
         excl = (excl > 0).astype(jnp.float32)
 
-    bn = 256
+    # the (S, bn, K) item tile is double-buffered in VMEM with K padded
+    # to 128 lanes; 256 items per tile unless that tile passes 4 MiB
+    lanes = -(-K // 128) * 128
+    bn = 256 if S * 256 * lanes * 4 <= 4 << 20 else 128
     v_p = pad_to_blocks(v, {1: bn})
     pad = v_p.shape[1] - N
     # padded items are excluded so they can never be selected
@@ -134,7 +156,7 @@ def topk_score(us: jnp.ndarray, v: jnp.ndarray, k: int, *,
     if not use_pallas:
         ids, mean, ex2 = _topk_ref_jit(us, v_p, excl_p, k_eff)
     else:
-        interpret = (not _ON_TPU) if interpret is None else interpret
+        interpret = _interpret(interpret)
         ids, mean, ex2, _ = topk_score_pallas(
             us, v_p, excl_p, k=k_eff, block_items=bn,
             interpret=interpret)
@@ -155,8 +177,8 @@ def sddmm(ug: jnp.ndarray, vg: jnp.ndarray, *, use_pallas: bool = False,
     """Gathered-operand SDDMM; see kernels/sddmm.py."""
     if not use_pallas:
         return ref.sddmm_ref(ug, vg)
-    interpret = (not _ON_TPU) if interpret is None else interpret
-    be, bk = 512, 128
+    interpret = _interpret(interpret)
+    be, bk = 1024, 128
     E = ug.shape[0]
     ug_p = pad_to_blocks(ug, {0: be, 1: bk})
     vg_p = pad_to_blocks(vg, {0: be, 1: bk})
@@ -252,7 +274,9 @@ KERNELS = {
                         (_sds((1025, 200)), _sds((1025, 200))),
                         _sddmm_call),
         ),
-        vmem_budget=2 << 20,
+        # the 1024-row block matches XLA's TPU tiling of the 1-D
+        # output; two double-buffered (1024, 128) f32 operands
+        vmem_budget=4 << 20,
         jit_fns=(sddmm_pallas,)),
     "topk_score": KernelSpec(
         "topk_score",

@@ -48,6 +48,33 @@ def gram_ref(vg: jnp.ndarray, val: jnp.ndarray, mask: jnp.ndarray):
     return gram, rhs
 
 
+def score_moments(u: jnp.ndarray, v: jnp.ndarray, n_samples: int):
+    """Posterior mean and E[score^2] of items against one user.
+
+    u (S, K) user rows, v (S, N, K) items -> mean (1, N), ex2 (1, N).
+    Every sum runs as explicit float32 multiplies and adds in a fixed
+    order (over K, then over samples), never as a dot or a reduction
+    whose order a compiler picks: XLA and the TPU kernel compiler
+    accumulate dots differently, and this is what lets the
+    ``topk_score`` kernel, which calls this on each item tile, agree
+    with the reference bitwise on every backend.  bf16 operands are
+    widened first, so their products are exact.
+    """
+    u = u.astype(jnp.float32)
+    vt = jnp.swapaxes(v.astype(jnp.float32), 1, 2)      # (S, K, N)
+    scores = u[:, 0:1] * vt[:, 0, :]
+    for k in range(1, u.shape[1]):
+        scores = scores + u[:, k:k + 1] * vt[:, k, :]   # (S, N)
+    first = scores[0:1]
+    mean, ex2 = first, first * first
+    for s in range(1, n_samples):
+        row = scores[s:s + 1]
+        mean = mean + row
+        ex2 = ex2 + row * row
+    inv_s = jnp.float32(1.0) / jnp.float32(n_samples)
+    return mean * inv_s, ex2 * inv_s
+
+
 def topk_score_ref(us: jnp.ndarray, v: jnp.ndarray,
                    excl: jnp.ndarray, k: int):
     """Posterior scoring + stable top-K — the serving oracle.
@@ -80,20 +107,11 @@ def topk_score_ref(us: jnp.ndarray, v: jnp.ndarray,
       on why per-path finalization broke bitwise equality).
     """
     S = v.shape[0]
-    bf16 = us.dtype == jnp.bfloat16 and v.dtype == jnp.bfloat16
-    if not bf16:
-        us = us.astype(jnp.float32)
-        v = v.astype(jnp.float32)
-    inv_s = jnp.float32(1.0) / jnp.float32(S)
 
     def one_user(args):
         u, ex = args                              # (S, K), (N,)
-        # per-sample scores; bf16 operands keep the pre-contraction
-        # ops in bf16 (same discipline as gram_ref), f32 accumulation
-        scores = jnp.einsum("snk,sk->sn", v, u,
-                            preferred_element_type=jnp.float32)
-        mean = jnp.sum(scores, axis=0) * inv_s
-        ex2 = jnp.sum(scores * scores, axis=0) * inv_s
+        mean, ex2 = score_moments(u, v, S)
+        mean, ex2 = mean[0], ex2[0]
         rank = jnp.where(ex > 0, -jnp.inf, mean)
         order = jnp.argsort(-rank)[:k].astype(jnp.int32)  # stable
         return order, mean[order], ex2[order]

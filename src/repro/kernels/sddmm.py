@@ -40,9 +40,14 @@ def _sddmm_kernel(ug_ref, vg_ref, out_ref):
 @functools.partial(
     jax.jit, static_argnames=("block_e", "block_k", "interpret"))
 def sddmm_pallas(ug: jnp.ndarray, vg: jnp.ndarray, *,
-                 block_e: int = 512, block_k: int = 128,
+                 block_e: int = 1024, block_k: int = 128,
                  interpret: bool = False) -> jnp.ndarray:
-    """pred (E,) = rowwise dot of ug (E, K) and vg (E, K)."""
+    """pred (E,) = rowwise dot of ug (E, K) and vg (E, K).
+
+    The 1-D output block must match XLA's TPU layout of a 1-D f32
+    array, which tiles it 1024 elements at a time: a 512-element block
+    is refused by the TPU compiler.
+    """
     E, K = ug.shape
     be = min(block_e, E)
     bk = min(block_k, K)

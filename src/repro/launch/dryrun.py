@@ -1,6 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("_DRYRUN_BASE_XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
+# placeholder devices on the host CPU, never an attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST run before any jax import (jax locks device count on first init).
 
 """Multi-pod dry-run: prove the distribution config is coherent.
@@ -42,6 +44,9 @@ from .roofline import roofline_terms
 from . import specs as S
 
 RESULTS = Path(__file__).resolve().parents[3] / "results" / "dryrun"
+
+# the device kind whose published peaks turn counts into modeled seconds
+DEVICE_KIND = "TPU v5 lite"
 
 
 def _get_cfg(arch: str, shape, variant: str):
@@ -122,7 +127,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, n_micro: int = 1,
         "lower_s": round(t_lower, 1),
         "compile_s": round(t_compile, 1),
     }
-    rec.update(roofline_terms(rec, cfg, shape))
+    rec.update(roofline_terms(rec, cfg, shape, DEVICE_KIND))
     return rec
 
 

@@ -34,10 +34,8 @@ from .. import compat
 def xla_cost_analysis(compiled) -> Dict[str, float]:
     """XLA's own per-device cost table for a compiled artifact.
 
-    Normalized through ``compat.cost_analysis`` (JAX 0.4.x returns a
-    one-element list, newer JAX a dict) so callers never branch on the
-    JAX version.  Kept here, next to the trip-count-aware analyzer it
-    cross-checks.
+    Read through ``compat.cost_analysis`` as a plain dict.  Kept here,
+    next to the trip-count-aware analyzer it cross-checks.
     """
     return compat.cost_analysis(compiled)
 

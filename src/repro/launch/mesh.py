@@ -3,8 +3,8 @@
 Never touches jax device state at import time — everything is a
 function, and the dry-run entry point is the only place that sets
 ``xla_force_host_platform_device_count``.  All mesh construction goes
-through ``repro.compat`` so the same code runs on JAX 0.4.x (no
-``AxisType``, no ``axis_types=`` kwarg) and on current releases.
+through ``repro.compat``, the one module that touches JAX's sharding
+API.
 """
 from __future__ import annotations
 

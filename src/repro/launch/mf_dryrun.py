@@ -1,6 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("_DRYRUN_BASE_XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
+# placeholder devices on the host CPU, never an attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST run before any jax import (jax locks device count on first init).
 
 """Multi-pod dry-run of the PAPER'S OWN WORKLOAD: the distributed
@@ -62,7 +64,7 @@ Variants:
                   ESS need >= 2 chains (``core.diagnostics``).
 
 Exchange model (per-sweep per-device seconds, in every record):
-    exchange_s_serial   collective_bytes / ICI_BW — the wire time,
+    exchange_s_serial   collective_bytes / ICI bandwidth — the wire time,
                         which the eager pipeline fully EXPOSES (the
                         blocking all-gather precedes every row solve
                         of its half-sweep)
@@ -91,6 +93,9 @@ import jax.numpy as jnp
 import numpy as np
 
 RESULTS = Path(__file__).resolve().parents[3] / "results" / "dryrun"
+
+# the device kind whose published peaks turn counts into modeled seconds
+DEVICE_KIND = "TPU v5 lite"
 
 F32 = jnp.float32
 I32 = jnp.int32
@@ -246,7 +251,7 @@ def lower_cell(cell: MFCell, mesh, variant: str):
                                     make_multi_chain_step)
     from ..core.gibbs import init_chain_states, init_state, stack_states
     from .hlo_cost import analyze as hlo_analyze
-    from .roofline import HBM_BW, ICI_BW, PEAK_FLOPS
+    from .roofline import peaks_for
 
     model = build_model(cell, variant)
     data = abstract_data(cell)
@@ -254,6 +259,7 @@ def lower_cell(cell: MFCell, mesh, variant: str):
     chains = 4 if "chains4" in variant else 1
     chain_axis = "chain" if chains > 1 else None
 
+    peaks = peaks_for(DEVICE_KIND)
     t0 = clock.perf_counter()
     # explicit shard_map sweep (one fixed-factor exchange per
     # half-sweep + K/K^2 moment psums); production cells are always in
@@ -289,9 +295,9 @@ def lower_cell(cell: MFCell, mesh, variant: str):
     bytes_hbm = (hc["bytes_materialized"]
                  + int(mem.argument_size_in_bytes)
                  + int(mem.output_size_in_bytes))
-    comp = hc["flops"] / PEAK_FLOPS
-    memt = bytes_hbm / HBM_BW
-    coll = hc["collective_bytes"]["total"] / ICI_BW
+    comp = hc["flops"] / peaks.flops
+    memt = bytes_hbm / peaks.hbm_bw
+    coll = hc["collective_bytes"]["total"] / peaks.ici_bw
     # overlap-aware exchange term: the eager all-gather blocks the
     # half-sweep it feeds (fully exposed wire time); the ring's
     # ppermute hops are double-buffered against the chunk-accumulated
@@ -326,7 +332,7 @@ def lower_cell(cell: MFCell, mesh, variant: str):
                         ("collective", coll), key=lambda kv: kv[1])[0],
         "model_flops": mf,
         "useful_flop_ratio": mf / hc["flops"] if hc["flops"] else 0.0,
-        "roofline_fraction": (mf / PEAK_FLOPS) / bound if bound else 0.0,
+        "roofline_fraction": (mf / peaks.flops) / bound if bound else 0.0,
         "contract": contract.asdict(),
         "contract_ok": not violations,
     }

@@ -19,8 +19,10 @@ fixture never needs a ring-mode regeneration.
 
 Tolerance: 1e-3 relative.  XLA reduction-order drift across versions
 measures ~1e-6..1e-5 on these trajectories; a changed draw sequence
-moves them by ~1e-1.  Regenerate INTENTIONALLY after an acknowledged
-chain-breaking change:
+moves them by ~1e-1.  The fixture records the JAX release whose random
+streams drew it: a release that changes a PRNG default (as 0.9 did with
+``jax_threefry_partitionable``) is a chain-breaking change.  Regenerate
+INTENTIONALLY after an acknowledged chain-breaking change:
 
     PYTHONPATH=src python tests/test_golden_chain.py --regen
 """
@@ -315,7 +317,11 @@ if __name__ == "__main__":
     import sys
     if "--regen" not in sys.argv:
         sys.exit("pass --regen to overwrite the fixture")
-    out = {"seed": SEED, "sweeps": SWEEPS, "chains": _run_all()}
+    import jax
+    # the random streams (e.g. the jax_threefry_partitionable default)
+    # belong to the JAX release that drew them
+    out = {"seed": SEED, "sweeps": SWEEPS, "jax": jax.__version__,
+           "chains": _run_all()}
     with open(FIXTURE, "w") as f:
         json.dump(out, f, indent=1)
     print("wrote", FIXTURE)

@@ -302,6 +302,10 @@ def chunked_dense_problem(draw, max_r=10, max_c=32, max_k=5):
     X = rng.normal(size=(R, C)).astype(np.float32)
     mask = np.ones((R, C), np.float32) if fully else \
         (rng.random((R, C)) < 0.7).astype(np.float32)
+    if not fully:
+        # an all-ones mask IS the fully-observed block (dense_block
+        # detects it), so a masked problem keeps one unobserved cell
+        mask[rng.integers(R), rng.integers(C)] = 0.0
     F = rng.normal(size=(C, K)).astype(np.float32)
     return X, mask, F, bounds, fully
 

@@ -8,8 +8,7 @@ import pytest
 
 from repro.launch.hlo_cost import (HloCost, analyze, parse_module,
                                    xla_cost_analysis)
-from repro.launch.roofline import (HBM_BW, ICI_BW, PEAK_FLOPS,
-                                   roofline_terms)
+from repro.launch.roofline import peaks_for, roofline_terms
 
 
 def _compile(f, *specs, **jit_kw):
@@ -105,19 +104,30 @@ def test_bytes_accessed_scales_with_trip():
 
 
 def test_roofline_terms_dominance():
-    rec = {"flops": PEAK_FLOPS, "bytes_accessed": HBM_BW / 10,
-           "collective_bytes": {"total": ICI_BW / 100}, "n_chips": 1}
+    peaks = peaks_for("TPU v5 lite")
+    rec = {"flops": peaks.flops, "bytes_accessed": peaks.hbm_bw / 10,
+           "collective_bytes": {"total": peaks.ici_bw / 100},
+           "n_chips": 1}
 
     class Cfg:
         pass
     from repro.configs import get_config, shape_by_name
     cfg = get_config("smollm_135m")
     shape = shape_by_name("train_4k")
-    out = roofline_terms(rec, cfg, shape)
+    out = roofline_terms(rec, cfg, shape, "TPU v5 lite")
     assert out["dominant"] == "compute"
     assert out["compute_s"] == pytest.approx(1.0)
     assert out["memory_s"] == pytest.approx(0.1)
     assert 0 < out["useful_flop_ratio"]
+
+
+def test_peaks_unknown_kind_raises():
+    """A device kind without published peaks is an error, not a
+    default; the v5e entry carries its published figures."""
+    v5e = peaks_for("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9, 16e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks_for("cpu")
 
 
 # ---------------------------------------------------------------------------
