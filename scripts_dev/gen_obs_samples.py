@@ -9,13 +9,16 @@ set, rerun::
 
     PYTHONPATH=src python scripts_dev/gen_obs_samples.py
 
-Three samples are written:
+Four samples are written:
 
 - ``train_trace.json``   — Chrome-trace export of an instrumented
-  TrainSession run (sweep spans with bytes_on_wire, session/compile)
+  TrainSession run (sweep spans with bytes_on_wire, session/compile,
+  the session/*, ckpt/* and gc spans)
 - ``train_metrics.json`` — the matching metrics snapshot
-- ``serve_metrics.json`` — a RecommendServer ``metrics_snapshot()``
-  after a short driven load (queue-wait/execute/occupancy histograms)
+- ``serve_trace.json``   — the RecommendServer's own trace after a
+  short driven load (serve/* spans, serve/step with step and ids)
+- ``serve_metrics.json`` — its ``metrics_snapshot()``
+  (queue-wait/execute/occupancy histograms)
 
 Wall-clock values in these files differ per run by design; the audit
 only pins structure.
@@ -89,6 +92,7 @@ def gen_serve(out_dir: str, store_dir: str) -> None:
         else:
             srv.submit(user=u, exclude=np.nonzero(obs[u])[0])
     srv.run()
+    srv.obs.write_trace(os.path.join(out_dir, "serve_trace.json"))
     write_json_atomic(os.path.join(out_dir, "serve_metrics.json"),
                       srv.metrics_snapshot())
 

@@ -21,7 +21,7 @@ from typing import List
 
 from ..obs import METRICS_FORMAT, TRACE_FORMAT
 
-_EVENT_PHASES = {"X", "i", "C"}
+_EVENT_PHASES = {"X", "C"}
 _SWEEP_PHASES = {"burnin", "sample"}
 _REGEN = ("regenerate with `python scripts_dev/gen_obs_samples.py`")
 
@@ -44,6 +44,7 @@ def _trace_findings(p: Path, doc: dict) -> List[str]:
         return out
     sweep_spans = 0
     compile_spans = 0
+    readback_spans = 0
     for i, ev in enumerate(events):
         where = f"{p}: traceEvents[{i}]"
         if not isinstance(ev, dict):
@@ -68,6 +69,19 @@ def _trace_findings(p: Path, doc: dict) -> List[str]:
                 out.append(f"{where} ({name}): {k} must be an int")
         if name == "session/compile":
             compile_spans += 1
+        if name == "session/readback":
+            readback_spans += 1
+        if name == "serve/step":
+            args = ev.get("args")
+            ids = args.get("ids") if isinstance(args, dict) else None
+            if not isinstance(args, dict) or \
+                    not isinstance(args.get("step"), int) or \
+                    not isinstance(ids, list) or \
+                    not all(isinstance(i, str) for i in ids) or \
+                    args.get("batch") != len(ids):
+                out.append(f"{where}: serve/step span args must carry "
+                           "the int server step index `step` and the "
+                           "request `ids` of its `batch`")
         if name == "sweep":
             sweep_spans += 1
             args = ev.get("args")
@@ -95,6 +109,10 @@ def _trace_findings(p: Path, doc: dict) -> List[str]:
             out.append(f"{p}: a session trace must carry the "
                        f"'session/compile' span (the compile_s / "
                        f"runtime_s split) — {_REGEN}")
+        if readback_spans == 0:
+            out.append(f"{p}: a session trace must carry the "
+                       f"'session/readback' spans (the wait for each "
+                       f"sweep) — {_REGEN}")
     return out
 
 

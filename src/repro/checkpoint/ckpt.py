@@ -143,18 +143,20 @@ class CheckpointManager:
                           ignore_errors=True)
 
     def save(self, step: int, tree: Any, blocking: bool = False) -> None:
-        self.wait()
+        with self.obs.span("ckpt/wait", cat="ckpt"):
+            self.wait()
         # materialize on host *before* handing to the thread so the
         # device buffers can be donated/freed by the train loop
-        host = jax.tree.map(np.asarray, tree)
+        with self.obs.span("ckpt/host_copy", cat="ckpt"):
+            host = jax.tree.map(np.asarray, tree)
         nbytes = sum(int(x.nbytes) for x in jax.tree.leaves(host))
 
         def work():
             t0 = self.obs.now()
-            save_pytree(host, os.path.join(self.dir, f"step_{step}"))
-            self._gc()
-            self.obs.complete("ckpt/save", t0, cat="ckpt", step=step,
-                              bytes=nbytes)
+            with self.obs.span("ckpt/save", cat="ckpt", step=step,
+                               bytes=nbytes):
+                save_pytree(host, os.path.join(self.dir, f"step_{step}"))
+                self._gc()
             self.obs.observe("ckpt.save_s", self.obs.now() - t0)
             self.obs.add("ckpt.saves")
             self.obs.add("ckpt.bytes_written", nbytes)

@@ -435,15 +435,17 @@ def _sharded_sweep(model: ModelDef, axes: Tuple[str, ...],
         """
         if o not in gathered:
             f = _wire_cast(factors[o])
-            if ring:
-                full0 = jnp.zeros((model.entities[o].n_rows, f.shape[1]),
-                                  f.dtype)
-                ag = _ring_accumulate(
-                    axes, sizes, shard, f, full0,
-                    lambda acc, chunk, c0:
-                        jax.lax.dynamic_update_slice(acc, chunk, (c0, 0)))
-            else:
-                ag = jax.lax.all_gather(f, axes, axis=0, tiled=True)
+            with jax.named_scope("exchange"):
+                if ring:
+                    full0 = jnp.zeros(
+                        (model.entities[o].n_rows, f.shape[1]), f.dtype)
+                    ag = _ring_accumulate(
+                        axes, sizes, shard, f, full0,
+                        lambda acc, chunk, c0:
+                            jax.lax.dynamic_update_slice(acc, chunk,
+                                                         (c0, 0)))
+                else:
+                    ag = jax.lax.all_gather(f, axes, axis=0, tiled=True)
             if model.bf16_gather:
                 # Keep the gathered value bf16 in the optimized graph:
                 # without the barrier the algebraic simplifier may hoist
@@ -464,8 +466,9 @@ def _sharded_sweep(model: ModelDef, axes: Tuple[str, ...],
         row_offset = shard * (ent.n_rows // S)
 
         # 1. hyper-parameters from psummed global moments
-        hyper = _psum_hyper(model, e, k_hyp, u, hypers[e], side, axes,
-                            ftf=ftf[e])
+        with jax.named_scope("hyper"):
+            hyper = _psum_hyper(model, e, k_hyp, u, hypers[e], side, axes,
+                                ftf=ftf[e])
 
         # 2. this shard's factor rows from their conditional
         prior = ent.prior
@@ -481,11 +484,12 @@ def _sharded_sweep(model: ModelDef, axes: Tuple[str, ...],
             hypers[e] = hyper
             gathered.pop(e, None)
             continue
-        Lam_p = prior.precision_term(hyper)
-        if isinstance(prior, MacauPrior):
-            b_p = prior.mean_term(hyper, ent.n_rows, side=side)
-        else:
-            b_p = prior.mean_term(hyper, ent.n_rows)
+        with jax.named_scope("hyper"):
+            Lam_p = prior.precision_term(hyper)
+            if isinstance(prior, MacauPrior):
+                b_p = prior.mean_term(hyper, ent.n_rows, side=side)
+            else:
+                b_p = prior.mean_term(hyper, ent.n_rows)
 
         gram_shared = None
         gram_rows = None
@@ -588,9 +592,10 @@ def _sharded_sweep(model: ModelDef, axes: Tuple[str, ...],
         if gram_shared is None and gram_rows is None:
             gram_shared = jnp.zeros(   # entity with no observed blocks
                 (model.num_latent, model.num_latent), jnp.float32)
-        factors[e] = _sample_normal_factor(k_fac, gram_shared, gram_rows,
-                                           rhs_acc, Lam_p, b_p,
-                                           row_offset=row_offset)
+        with jax.named_scope("solve"):
+            factors[e] = _sample_normal_factor(
+                k_fac, gram_shared, gram_rows, rhs_acc, Lam_p, b_p,
+                row_offset=row_offset)
         hypers[e] = hyper
         gathered.pop(e, None)   # any cached view of e is now stale
 
@@ -608,20 +613,25 @@ def _sharded_sweep(model: ModelDef, axes: Tuple[str, ...],
         v = factors[e_last]
         if model.bf16_gather:
             v = v.astype(jnp.bfloat16)
-        if blk.sparse:
-            padded = payload.rows if blk.row_entity == e_last \
-                else payload.cols
-            vals, msk = padded.val, padded.mask
-            pred = jnp.einsum("rtk,rk->rt", fixed[padded.idx], v)
-        else:
-            vals, msk = payload.oriented(blk.row_entity == e_last)
-            pred = v @ fixed.T
-        resid = (vals - pred) * msk
-        se = psum(jnp.sum(resid * resid))
-        nnz = psum(jnp.sum(msk))
-        noises[bi] = blk.noise.sample_state(nkeys[bi], noises[bi], pred,
-                                            vals, msk, sse=se, nnz=nnz)
-        metrics[f"rmse_train_{bi}"] = jnp.sqrt(se / jnp.maximum(nnz, 1.0))
+        with jax.named_scope("residuals"):
+            if blk.sparse:
+                padded = payload.rows if blk.row_entity == e_last \
+                    else payload.cols
+                vals, msk = padded.val, padded.mask
+                pred = jnp.einsum("rtk,rk->rt", fixed[padded.idx], v)
+            else:
+                vals, msk = payload.oriented(blk.row_entity == e_last)
+                pred = v @ fixed.T
+            resid = (vals - pred) * msk
+            se = psum(jnp.sum(resid * resid))
+            nnz = psum(jnp.sum(msk))
+        with jax.named_scope("noise"):
+            noises[bi] = blk.noise.sample_state(nkeys[bi], noises[bi],
+                                                pred, vals, msk, sse=se,
+                                                nnz=nnz)
+        with jax.named_scope("metrics"):
+            metrics[f"rmse_train_{bi}"] = jnp.sqrt(
+                se / jnp.maximum(nnz, 1.0))
         metrics[f"alpha_{bi}"] = noises[bi]["alpha"]
 
     new_state = MFState(key, tuple(factors), tuple(hypers), tuple(noises),

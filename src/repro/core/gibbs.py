@@ -146,7 +146,8 @@ def _sparse_contrib(model: ModelDef, mat: SparseMatrix, as_row: bool,
     augmentation draws bitwise slices of the single-device draws.
     """
     padded = mat.rows if as_row else mat.cols
-    vg = fixed[padded.idx]                      # (R, T, K)
+    with jax.named_scope("gather"):
+        vg = fixed[padded.idx]                  # (R, T, K)
     if isinstance(noise, ProbitNoise):
         pred = jnp.einsum("rtk,rk->rt", vg, u_cur)
         vals, alpha = noise.augment(key, nstate, pred, padded.val,
@@ -154,9 +155,10 @@ def _sparse_contrib(model: ModelDef, mat: SparseMatrix, as_row: bool,
     else:
         vals, alpha = noise.augment(key, nstate, None, padded.val,
                                     padded.mask, row_offset=row_offset)
-    gram, rhs = ops.gram_and_rhs(vg, vals, padded.mask,
-                                 use_pallas=model.use_pallas)
-    return alpha * gram, alpha * rhs            # (R,K,K), (R,K)
+    with jax.named_scope("gram"):
+        gram, rhs = ops.gram_and_rhs(vg, vals, padded.mask,
+                                     use_pallas=model.use_pallas)
+        return alpha * gram, alpha * rhs        # (R,K,K), (R,K)
 
 
 def _dense_contrib(payload: DenseBlock, as_row: bool, fixed: jnp.ndarray,
@@ -177,13 +179,14 @@ def _dense_contrib(payload: DenseBlock, as_row: bool, fixed: jnp.ndarray,
     else:
         vals, alpha = noise.augment(key, nstate, None, X, m,
                                     row_offset=row_offset)
-    if payload.fully:
-        gram_shared = alpha * (fixed.T @ fixed)             # (K, K)
-        rhs = alpha * (vals @ fixed)                        # (R, K)
-        return gram_shared, None, rhs
-    gram_rows = alpha * jnp.einsum("rc,ck,cl->rkl", m, fixed, fixed)
-    rhs = alpha * ((vals * m) @ fixed)
-    return None, gram_rows, rhs
+    with jax.named_scope("gram"):
+        if payload.fully:
+            gram_shared = alpha * (fixed.T @ fixed)         # (K, K)
+            rhs = alpha * (vals @ fixed)                    # (R, K)
+            return gram_shared, None, rhs
+        gram_rows = alpha * jnp.einsum("rc,ck,cl->rkl", m, fixed, fixed)
+        rhs = alpha * ((vals * m) @ fixed)
+        return None, gram_rows, rhs
 
 
 def _dense_chunk_contrib(vals: jnp.ndarray, m: jnp.ndarray, fully: bool,
@@ -453,11 +456,12 @@ def _gather_view(model: ModelDef, factors):
             return jax.lax.all_gather(x.astype(jnp.bfloat16), axes,
                                       axis=0, tiled=True)
 
-        return compat.shard_map(
-            body, mesh=mesh,
-            in_specs=jax.sharding.PartitionSpec(axes),
-            out_specs=jax.sharding.PartitionSpec(),
-            check=False)(f)
+        with jax.named_scope("exchange"):
+            return compat.shard_map(
+                body, mesh=mesh,
+                in_specs=jax.sharding.PartitionSpec(axes),
+                out_specs=jax.sharding.PartitionSpec(),
+                check=False)(f)
 
     return tuple(cast(f) for f in factors)
 
@@ -472,10 +476,11 @@ def _entity_update(model: ModelDef, data: MFData, key, e: int,
     u = factors[e]
 
     # 1. hyper-parameters from the current factor (Algorithm 1 line 2/5)
-    if isinstance(prior, MacauPrior):
-        hyper = prior.sample_hyper(k_hyp, u, hypers[e], side=side)
-    else:
-        hyper = prior.sample_hyper(k_hyp, u, hypers[e])
+    with jax.named_scope("hyper"):
+        if isinstance(prior, MacauPrior):
+            hyper = prior.sample_hyper(k_hyp, u, hypers[e], side=side)
+        else:
+            hyper = prior.sample_hyper(k_hyp, u, hypers[e])
 
     # 2. factor matrix from its conditional
     gview = _gather_view(model, factors)
@@ -484,11 +489,12 @@ def _entity_update(model: ModelDef, data: MFData, key, e: int,
                                    lambda o: gview[o], noises)
         return u_new, hyper
 
-    Lam_p = prior.precision_term(hyper)
-    if isinstance(prior, MacauPrior):
-        b_p = prior.mean_term(hyper, ent.n_rows, side=side)
-    else:
-        b_p = prior.mean_term(hyper, ent.n_rows)
+    with jax.named_scope("hyper"):
+        Lam_p = prior.precision_term(hyper)
+        if isinstance(prior, MacauPrior):
+            b_p = prior.mean_term(hyper, ent.n_rows, side=side)
+        else:
+            b_p = prior.mean_term(hyper, ent.n_rows)
 
     gram_shared = None
     gram_rows = None
@@ -514,8 +520,9 @@ def _entity_update(model: ModelDef, data: MFData, key, e: int,
     if gram_shared is None and gram_rows is None:
         gram_shared = jnp.zeros((model.num_latent, model.num_latent),
                                 jnp.float32)
-    u_new = _sample_normal_factor(k_fac, gram_shared, gram_rows,
-                                  rhs_acc, Lam_p, b_p)
+    with jax.named_scope("solve"):
+        u_new = _sample_normal_factor(k_fac, gram_shared, gram_rows,
+                                      rhs_acc, Lam_p, b_p)
     return u_new, hyper
 
 
@@ -554,14 +561,17 @@ def gibbs_step(model: ModelDef, data: MFData, state: MFState
     nkeys = jax.random.split(nkey, max(1, len(model.blocks)))
     gview = _gather_view(model, tuple(factors))
     for bi, blk in enumerate(model.blocks):
-        pred, vals, mask = _block_pred_observed(model, data, bi, gview)
-        noises[bi] = blk.noise.sample_state(nkeys[bi], noises[bi], pred,
-                                            vals, mask)
-        se = jnp.sum(((vals - pred) * mask) ** 2)
-        # all-masked blocks (padded shard views) have nnz == 0: report
-        # rmse 0 instead of 0/0 -> NaN poisoning the metric trace
-        metrics[f"rmse_train_{bi}"] = jnp.sqrt(
-            se / jnp.maximum(jnp.sum(mask), 1.0))
+        with jax.named_scope("residuals"):
+            pred, vals, mask = _block_pred_observed(model, data, bi, gview)
+        with jax.named_scope("noise"):
+            noises[bi] = blk.noise.sample_state(nkeys[bi], noises[bi],
+                                                pred, vals, mask)
+        with jax.named_scope("metrics"):
+            se = jnp.sum(((vals - pred) * mask) ** 2)
+            # all-masked blocks (padded shard views) have nnz == 0:
+            # report rmse 0 instead of 0/0 -> NaN poisoning the trace
+            metrics[f"rmse_train_{bi}"] = jnp.sqrt(
+                se / jnp.maximum(jnp.sum(mask), 1.0))
         metrics[f"alpha_{bi}"] = noises[bi]["alpha"]
 
     new_state = MFState(key, tuple(factors), tuple(hypers), tuple(noises),

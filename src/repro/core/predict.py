@@ -786,14 +786,19 @@ class PredictSession:
                 "them with user_rows()/cold_rows()")
         _, ie = self._block_entities(block)
         n_items = self.model.entities[ie].n_rows
-        mask = self._exclude_mask(exclude, rows.shape[0], n_items)
+        with self.obs.span("predict/mask", cat="predict"):
+            mask = self._exclude_mask(exclude, rows.shape[0], n_items)
+            if mask is not None:
+                mask = jnp.asarray(mask)
         cache = self.warm_cache()
         if cache is not None:
-            ids, mean, std = ops.topk_score(
-                rows, cache.factors[ie], k, exclude=mask,
-                use_pallas=self.model.use_pallas)
-            return RecResult(np.asarray(ids), np.asarray(mean),
-                             np.asarray(std))
+            with self.obs.span("predict/score", cat="predict"):
+                ids, mean, std = ops.topk_score(
+                    rows, cache.factors[ie], k, exclude=mask,
+                    use_pallas=self.model.use_pallas)
+            with self.obs.span("predict/readback", cat="predict"):
+                return RecResult(np.asarray(ids), np.asarray(mean),
+                                 np.asarray(std))
         return self._recommend_rows_lazy(rows, k, ie, mask)
 
     def _recommend_rows_lazy(self, rows, k, item_entity, mask

@@ -54,7 +54,7 @@ from ..obs import clock, resolve_recorder
 from .blocks import (BlockDef, DenseBlock, EntityDef, ModelDef,
                      dense_block)
 from .diagnostics import (Diagnostics, compute_diagnostics,
-                          save_diagnostics, split_rhat)
+                          save_diagnostics)
 from .gibbs import (MFData, MFState, gibbs_step, init_chain_states,
                     init_state, multi_chain_step_jit, stack_states,
                     unstack_state)
@@ -656,8 +656,14 @@ class Session:
 
     def run(self, keep_samples: bool = False,
             resume: bool = False) -> SessionResult:
-        model, data = self.model, self.data
         rec = resolve_recorder(self.recorder)
+        # every garbage collection of the run is a ``gc`` span
+        with rec.gc_spans():
+            return self._run(rec, keep_samples, resume)
+
+    def _run(self, rec, keep_samples: bool,
+             resume: bool) -> SessionResult:
+        model, data = self.model, self.data
         rec.set_kind("session")
         C = self.chains
         if C == 1:
@@ -712,8 +718,7 @@ class Session:
             compile_s = clock.perf_counter() - t_c
             rec.complete("session/compile", t_c, cat="session",
                          phase="compile")
-        obs_on = rec.enabled
-        bytes_on_wire = self._wire_bytes() if obs_on else 0
+        bytes_on_wire = self._wire_bytes() if rec.enabled else 0
         t0 = clock.perf_counter()
         n_blocks = len(model.blocks)
         train_traces: List[List[float]] = [[] for _ in range(n_blocks)]
@@ -732,97 +737,86 @@ class Session:
         # feeding split-R-hat / bulk-ESS at the end of the run
         diag_traces: Dict[str, List[np.ndarray]] = {}
 
+        # one span per stage of the loop: the sweep (dispatch through
+        # the metrics readback, which waits for it), then the host's
+        # work on its result
         for sweep in range(start, total):
-            if obs_on:
-                t_sweep = rec.now()
-            state, metrics = step(data, state)
-            if obs_on:
-                # fence: device time for THIS sweep, not dispatch time
-                jax.block_until_ready((state, metrics))
-                t_done = rec.now()
-            for bi in range(n_blocks):
-                arr = np.atleast_1d(
-                    np.asarray(metrics[f"rmse_train_{bi}"]))
-                train_traces[bi].append(float(arr[0]))
-                for c in range(C):
-                    chain_train_traces[c][bi].append(float(arr[c]))
             in_sampling = sweep >= self.burnin
+            phase = "sample" if in_sampling else "burnin"
+            t_sweep = rec.now()
+            with rec.span("sweep", cat="session", sweep=sweep, phase=phase,
+                          stage="first" if sweep == start else "steady",
+                          bytes_on_wire=bytes_on_wire):
+                state, metrics = step(data, state)
+                with rec.span("session/readback", cat="session"):
+                    for bi in range(n_blocks):
+                        arr = np.atleast_1d(
+                            np.asarray(metrics[f"rmse_train_{bi}"]))
+                        train_traces[bi].append(float(arr[0]))
+                        for c in range(C):
+                            chain_train_traces[c][bi].append(float(arr[c]))
+            rec.observe("session.sweep_s", rec.now() - t_sweep)
+            rec.add("session.sweeps")
             if in_sampling:
-                # pool posterior draws across chains: step-major,
-                # chain-minor — the summation order PredictSession
-                # replays from a multi-chain store
-                for bi, acc in accs.items():
-                    blk = model.blocks[bi]
-                    if C == 1:
-                        acc.update(state.factors[blk.row_entity],
-                                   state.factors[blk.col_entity])
-                    else:
-                        for c in range(C):
-                            acc.update(
-                                state.factors[blk.row_entity][c],
-                                state.factors[blk.col_entity][c])
-                    test_traces[bi].append(
-                        float(jnp.sqrt(jnp.mean(
-                            (acc.mean - acc.test.v) ** 2))))
-                if keep_samples:
-                    if C == 1:
-                        samples.append(tuple(np.asarray(f)
-                                             for f in state.factors))
-                    else:
-                        for c in range(C):
-                            samples.append(tuple(np.asarray(f[c])
+                with rec.span("session/accumulate", cat="session"):
+                    # pool posterior draws across chains: step-major,
+                    # chain-minor — the summation order PredictSession
+                    # replays from a multi-chain store
+                    for bi, acc in accs.items():
+                        blk = model.blocks[bi]
+                        if C == 1:
+                            acc.update(state.factors[blk.row_entity],
+                                       state.factors[blk.col_entity])
+                        else:
+                            for c in range(C):
+                                acc.update(
+                                    state.factors[blk.row_entity][c],
+                                    state.factors[blk.col_entity][c])
+                        test_traces[bi].append(
+                            float(jnp.sqrt(jnp.mean(
+                                (acc.mean - acc.test.v) ** 2))))
+                    if keep_samples:
+                        if C == 1:
+                            samples.append(tuple(np.asarray(f)
                                                  for f in state.factors))
-                if sums is not None:
-                    sums = [s + f for s, f in zip(sums, state.factors)]
-                    n_acc += 1
-                for nm, v in metrics.items():
-                    diag_traces.setdefault(nm, []).append(
-                        np.atleast_1d(np.asarray(v, np.float64)))
-                for e, ent in enumerate(model.entities):
-                    f = state.factors[e]
-                    rms = jnp.sqrt(jnp.mean(
-                        f * f, axis=None if C == 1 else (1, 2)))
-                    diag_traces.setdefault(
-                        f"factor_rms_{ent.name}", []).append(
-                        np.atleast_1d(np.asarray(rms, np.float64)))
+                        else:
+                            for c in range(C):
+                                samples.append(tuple(
+                                    np.asarray(f[c])
+                                    for f in state.factors))
+                    if sums is not None:
+                        sums = [s + f for s, f in zip(sums, state.factors)]
+                        n_acc += 1
+                    for nm, v in metrics.items():
+                        diag_traces.setdefault(nm, []).append(
+                            np.atleast_1d(np.asarray(v, np.float64)))
+                    for e, ent in enumerate(model.entities):
+                        f = state.factors[e]
+                        rms = jnp.sqrt(jnp.mean(
+                            f * f, axis=None if C == 1 else (1, 2)))
+                        diag_traces.setdefault(
+                            f"factor_rms_{ent.name}", []).append(
+                            np.atleast_1d(np.asarray(rms, np.float64)))
                 if savers and \
                         (sweep - self.burnin + 1) % self.save_freq == 0:
-                    if C == 1:
-                        savers[0].save(sweep + 1, state)
-                    else:
-                        for c, sv in enumerate(savers):
-                            sv.save(sweep + 1, unstack_state(state, c))
-            if obs_on:
-                span_args = {
-                    "sweep": sweep,
-                    "phase": "sample" if in_sampling else "burnin",
-                    "stage": "first" if sweep == start else "steady",
-                    "bytes_on_wire": bytes_on_wire,
-                }
-                tr = diag_traces.get("rmse_train_0")
-                if tr:
-                    # streaming convergence: split-R-hat over the
-                    # post-burnin draws so far (nan below MIN_DRAWS)
-                    rhat = split_rhat(np.stack(tr, axis=1))
-                    if np.isfinite(rhat):
-                        span_args["rhat_rmse_train_0"] = rhat
-                rec.complete("sweep", t_sweep, end=t_done,
-                             cat="session", **span_args)
-                rec.observe("session.sweep_s", t_done - t_sweep)
-                rec.add("session.sweeps")
+                    with rec.span("session/save", cat="session"):
+                        if C == 1:
+                            savers[0].save(sweep + 1, state)
+                        else:
+                            for c, sv in enumerate(savers):
+                                sv.save(sweep + 1, unstack_state(state, c))
             if self.verbose and (sweep % max(1, total // 20) == 0):
-                ph = "burnin" if sweep < self.burnin else "sample"
-                print(f"[{ph} {sweep:4d}] rmse_train="
+                print(f"[{phase} {sweep:4d}] rmse_train="
                       f"{train_traces[0][-1]:.4f}")
             if self.callbacks:
-                phase = "sample" if in_sampling else "burnin"
-                if C == 1:
-                    info = SweepInfo(sweep, phase, state, metrics)
-                else:
-                    m0 = {k: v[0] for k, v in metrics.items()}
-                    info = SweepInfo(sweep, phase, state, m0, metrics)
-                for cb in self.callbacks:
-                    cb(info)
+                with rec.span("session/callbacks", cat="session"):
+                    if C == 1:
+                        info = SweepInfo(sweep, phase, state, metrics)
+                    else:
+                        m0 = {k: v[0] for k, v in metrics.items()}
+                        info = SweepInfo(sweep, phase, state, m0, metrics)
+                    for cb in self.callbacks:
+                        cb(info)
         for sv in savers:
             sv.wait()
 

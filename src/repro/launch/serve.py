@@ -162,6 +162,7 @@ class SlotServer:
         self.done: List[Dict[str, Any]] = []
         self._next_id = 0                 # never reused, ever
         self._live_ids: set = set()       # queued + active
+        self._steps = 0                   # service steps taken
 
     def _enqueue(self, req: Dict[str, Any],
                  req_id: Optional[str]) -> str:
@@ -217,12 +218,15 @@ class SlotServer:
         raise NotImplementedError
 
     def run(self, max_steps: int = 10_000) -> List[Dict[str, Any]]:
-        """Service steps until all requests finish; returns results."""
-        for _ in range(max_steps):
-            self._admit()
-            if not any(self.active):
-                break
-            self.step()
+        """Service steps until all requests finish; returns results.
+        Garbage collections inside are ``gc`` spans."""
+        with self.obs.gc_spans():
+            for _ in range(max_steps):
+                with self.obs.span("serve/admit", cat="serve"):
+                    self._admit()
+                if not any(self.active):
+                    break
+                self.step()
         return self.done
 
 
@@ -342,32 +346,39 @@ class RecommendServer(SlotServer):
              list(map(int, exclude))}, req_id)
 
     def step(self):
-        """Score every active request in one batched kernel call."""
+        """Score every active request in one batched kernel call.
+
+        One ``serve/step`` span (args: ``batch``, the server's ``step``
+        index and the request ``ids``) holds the session's
+        ``predict/*`` spans and ``serve/finish``."""
         live = [(s, r) for s, r in enumerate(self.active)
                 if r is not None]
         self._observe_batch(len(live))
-        t_step = self.obs.now()
-        rows = []
-        for _, req in live:
-            if req["user"] is not None:
-                rows.append(self.session.user_rows([req["user"]],
-                                                   self.block))
-            else:
-                rows.append(self.session.cold_rows(req["features"],
-                                                   self.block))
-        batch = jnp.concatenate(rows, axis=0)        # (B, S, K)
-        k_max = max(req["k"] for _, req in live)
-        excl = [req["exclude"] or [] for _, req in live]
-        res = self.session.recommend_rows(batch, k_max, self.block,
-                                          exclude=excl)
-        # trim each slot to ITS k: the selection loop picks the same
-        # first k entries whatever the total K, so a larger shared
-        # batch never changes a request's answer
-        for b, (s, req) in enumerate(live):
-            kk = min(req["k"], res.ids.shape[1])
-            req["ids"] = res.ids[b, :kk].copy()
-            req["mean"] = res.mean[b, :kk].copy()
-            req["std"] = res.std[b, :kk].copy()
-            self._finish(s)
-        self.obs.complete("serve/step", t_step, cat="serve",
-                          batch=len(live))
+        step, self._steps = self._steps, self._steps + 1
+        pobs = self.session.obs
+        with self.obs.span("serve/step", cat="serve", batch=len(live),
+                           step=step, ids=[r["id"] for _, r in live]):
+            with pobs.span("predict/rows", cat="predict"):
+                rows = []
+                for _, req in live:
+                    if req["user"] is not None:
+                        rows.append(self.session.user_rows(
+                            [req["user"]], self.block))
+                    else:
+                        rows.append(self.session.cold_rows(
+                            req["features"], self.block))
+                batch = jnp.concatenate(rows, axis=0)    # (B, S, K)
+            k_max = max(req["k"] for _, req in live)
+            excl = [req["exclude"] or [] for _, req in live]
+            res = self.session.recommend_rows(batch, k_max, self.block,
+                                              exclude=excl)
+            # trim each slot to ITS k: the selection loop picks the same
+            # first k entries whatever the total K, so a larger shared
+            # batch never changes a request's answer
+            with self.obs.span("serve/finish", cat="serve"):
+                for b, (s, req) in enumerate(live):
+                    kk = min(req["k"], res.ids.shape[1])
+                    req["ids"] = res.ids[b, :kk].copy()
+                    req["mean"] = res.mean[b, :kk].copy()
+                    req["std"] = res.std[b, :kk].copy()
+                    self._finish(s)

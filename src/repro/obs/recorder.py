@@ -4,31 +4,46 @@ Determinism contract (the reason this subsystem exists as *one*
 module instead of ad-hoc timers):
 
 - A disabled Recorder never reads the clock.  Every public method
-  checks ``self.enabled`` before anything else, so ``REPRO_OBS``
-  unset costs one attribute load + branch per call site.
+  checks ``self.enabled`` before reading it or recording, so
+  ``REPRO_OBS`` unset costs one attribute load + branch per call site
+  (plus, in ``span``, an inactive profiler annotation).
 - Wall-clock values are only ever *recorded*, never fed back into a
-  computation, and timing always happens outside jitted code (span
-  ends are fenced with ``jax.block_until_ready`` by the caller).
+  computation, and timing always happens outside jitted code.
   Together these make sampled chains bitwise-invariant to
   instrumentation — asserted in tests/test_golden_chain.py and
   tests/test_multichain.py.
-- All mutation happens under one lock: the checkpoint manager's
-  background save thread and the serving loop write into the same
-  Recorder concurrently.
+- All mutation happens under one (re-entrant) lock: the checkpoint
+  manager's background save thread and the serving loop write into
+  the same Recorder concurrently.
 
 Span timestamps are relative to the Recorder's construction (its
 trace epoch), exported in Chrome trace-event microseconds.
+
+Every ``span`` also enters a ``jax.profiler.TraceAnnotation`` under its
+name, enabled or not: a no-op unless a profiler session is active, and
+then the span lands in the profiler's trace on the device trace's
+clock, on the thread that did the work.  The in-memory record is kept
+in a ring of ``MAX_EVENTS`` events, so an always-on recorder (the
+serving layer's) has bounded memory; overflow is counted in
+``obs.events_dropped``.
 """
 from __future__ import annotations
 
+import gc
 import os
 import threading
+from collections import deque
 from contextlib import contextmanager
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Deque, Dict, Optional, Sequence
+
+from jax.profiler import TraceAnnotation
 
 from . import clock
 from .metrics import (Histogram, METRICS_FORMAT, TRACE_FORMAT,
                       latency_buckets, prometheus_text, write_json_atomic)
+
+# events kept in memory; older ones are dropped (and counted) first
+MAX_EVENTS = 65_536
 
 
 def obs_enabled() -> bool:
@@ -47,8 +62,10 @@ class Recorder:
 
     def __init__(self, enabled: bool = True):
         self.enabled = bool(enabled)
-        self._lock = threading.Lock()
-        self._events: List[dict] = []
+        # re-entrant: a ``gc`` span is recorded from inside whatever the
+        # collection interrupted, a locked section of this recorder too
+        self._lock = threading.RLock()
+        self._events: Deque[dict] = deque(maxlen=MAX_EVENTS)
         self._counters: Dict[str, float] = {}
         self._gauges: Dict[str, float] = {}
         self._hists: Dict[str, Histogram] = {}
@@ -69,6 +86,9 @@ class Recorder:
     def _push(self, event: dict) -> None:
         with self._lock:
             event["tid"] = self._tid()
+            if len(self._events) == MAX_EVENTS:
+                self._counters["obs.events_dropped"] = \
+                    self._counters.get("obs.events_dropped", 0.0) + 1
             self._events.append(event)
 
     # -- spans -------------------------------------------------------
@@ -96,22 +116,46 @@ class Recorder:
 
     @contextmanager
     def span(self, name: str, cat: str = "obs", **args: Any):
-        """Context-manager span for non-hot paths (cache warm, restore)."""
-        if not self.enabled:
-            yield
-            return
-        t0 = clock.perf_counter()
+        """Context-manager span around the work it names.
+
+        The profiler annotation (``name`` only) is entered whether or
+        not the recorder is enabled; the clock is read and the event
+        recorded only when it is."""
+        with TraceAnnotation(name):
+            if not self.enabled:
+                yield
+                return
+            t0 = clock.perf_counter()
+            try:
+                yield
+            finally:
+                self.complete(name, t0, cat=cat, **args)
+
+    @contextmanager
+    def gc_spans(self):
+        """While open, every garbage collection in the process is a
+        ``gc`` span (args ``generation``, ``collected``) on the thread
+        it interrupts.  Collections never overlap, so one pending
+        annotation is enough."""
+        pending = []
+
+        def hook(phase: str, info: dict) -> None:
+            if phase == "start":
+                ann = TraceAnnotation("gc")
+                ann.__enter__()
+                pending.append((ann, self.now()))
+            elif pending:
+                ann, t0 = pending.pop()
+                ann.__exit__(None, None, None)
+                self.complete("gc", t0, cat="gc",
+                              generation=info["generation"],
+                              collected=info["collected"])
+
+        gc.callbacks.append(hook)
         try:
             yield
         finally:
-            self.complete(name, t0, cat=cat, **args)
-
-    def instant(self, name: str, cat: str = "obs", **args: Any) -> None:
-        if not self.enabled:
-            return
-        self._push({"name": name, "cat": cat, "ph": "i", "s": "t",
-                    "ts": (clock.perf_counter() - self._epoch) * 1e6,
-                    "pid": 0, "args": args})
+            gc.callbacks.remove(hook)
 
     # -- metrics -----------------------------------------------------
 
@@ -173,7 +217,8 @@ class Recorder:
         """Chrome trace-event JSON object (load in chrome://tracing or
         https://ui.perfetto.dev)."""
         with self._lock:
-            events = [dict(e) for e in self._events]
+            # copied in one call: a collection's span may land meanwhile
+            events = [dict(e) for e in list(self._events)]
         out = {"traceEvents": events, "displayTimeUnit": "ms",
                "repro": {"format": TRACE_FORMAT}}
         if self._kind:

@@ -288,15 +288,16 @@ def test_recorder_does_not_fork_golden_chain(tmp_path):
 
     mat, _, _ = random_sparse(SEED, (48, 32), 0.3, rank=3)
 
-    def run(recorder):
+    def run(recorder, sub):
         s = TrainSession(num_latent=4, burnin=2, nsamples=3,
-                         seed=SEED, chains=1, recorder=recorder)
+                         seed=SEED, chains=1, recorder=recorder,
+                         save_freq=1, save_dir=str(tmp_path / sub))
         s.add_train_and_test(mat, noise=AdaptiveGaussian())
         return s.run()
 
-    off = run(Recorder(enabled=False))
+    off = run(Recorder(enabled=False), "off")
     rec = Recorder(enabled=True)
-    on = run(rec)
+    on = run(rec, "on")
 
     assert on.rmse_train_trace == off.rmse_train_trace
     assert on.rmse_test_trace == off.rmse_test_trace
@@ -304,9 +305,16 @@ def test_recorder_does_not_fork_golden_chain(tmp_path):
     for x, y in zip(jax.tree.leaves(on.state),
                     jax.tree.leaves(off.state)):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-    # the enabled run actually recorded: compile split + sweep spans
-    names = {e["name"] for e in rec.trace()["traceEvents"]}
-    assert {"session/compile", "sweep"} <= names
+    # the enabled run actually recorded: compile split, the sweep
+    # loop's spans and the sample writer's
+    events = rec.trace()["traceEvents"]
+    names = {e["name"] for e in events}
+    assert {"session/compile", "sweep", "session/readback",
+            "session/accumulate", "session/save", "ckpt/wait",
+            "ckpt/host_copy", "ckpt/save"} <= names
+    assert all(set(e["args"]) == {"sweep", "phase", "stage",
+                                  "bytes_on_wire"}
+               for e in events if e["name"] == "sweep")
     assert rec.counter("session.sweeps") == 5.0
     # and the split is visible in the result
     assert on.compile_s > 0.0

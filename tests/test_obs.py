@@ -1,6 +1,6 @@
-"""The ``repro.obs`` observability subsystem (PR 10).
+"""The ``repro.obs`` observability subsystem.
 
-Three contract families:
+Four contract families:
 
 1. **Primitives** — fixed-bucket histograms (observe/percentile/
    serialization round-trip), recorder span/counter/gauge semantics,
@@ -12,6 +12,10 @@ Three contract families:
    emit a loadable Chrome trace with contract-derived
    ``bytes_on_wire`` on every sweep span; ``PredictSession`` exposes
    cache hit/miss stats; the module-level spec cache is a bounded LRU.
+4. **One clock** — every span reaches the JAX profiler's trace, on the
+   thread that did the work and inside its parent, with the recorder
+   on or off; ``gc`` spans count collections; the event ring is
+   bounded; the sweep's stages are named in the compiled HLO.
 """
 import json
 import math
@@ -118,7 +122,7 @@ def test_recorder_span_counter_gauge_and_trace_shape():
     rec = Recorder(enabled=True)
     rec.set_kind("session")
     with rec.span("phase/work", cat="test", step=3):
-        rec.instant("marker", cat="test")
+        pass
     rec.add("n", 2)
     rec.add("n")
     rec.gauge("depth", 4.0)
@@ -130,10 +134,6 @@ def test_recorder_span_counter_gauge_and_trace_shape():
     span = by_name["phase/work"]
     assert span["ph"] == "X" and span["dur"] >= 0 \
         and span["args"]["step"] == 3
-    assert by_name["marker"]["ph"] == "i"
-    # instant fired inside the span's window
-    assert span["ts"] <= by_name["marker"]["ts"] \
-        <= span["ts"] + span["dur"]
 
     m = rec.metrics()
     assert m["format"] == METRICS_FORMAT and m["kind"] == "session"
@@ -314,3 +314,247 @@ def test_spec_cache_is_a_bounded_lru(tmp_path, monkeypatch):
     # LRU: oldest store evicted, newest two resident
     predict.PredictSession(stores[2])
     assert predict.spec_cache_stats()["hits"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# one clock: the program's spans in the JAX profiler's trace
+# ---------------------------------------------------------------------------
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a JAX profiler trace; return the spans of each
+    host Python thread, one ``[(start, end, name)]`` list per thread."""
+    import jax
+    from jax.profiler import ProfileData
+
+    d = tmp_path / "profile"
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(d), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = d.rglob("*.xplane.pb")
+    return [[(e.start_ns, e.start_ns + e.duration_ns, e.name)
+             for e in ln.events]
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:CPU")
+            for ln in plane.lines if ln.name.startswith("python")]
+
+
+def _inside(thread, child, parent):
+    """Every ``child`` span of the thread lies within a ``parent``."""
+    kids = [s for s in thread if s[2] == child]
+    outer = [s for s in thread if s[2] == parent]
+    return bool(kids) and all(
+        any(p[0] <= k[0] and k[1] <= p[1] for p in outer) for k in kids)
+
+
+def test_program_spans_land_in_the_profiler_trace(tmp_path, monkeypatch):
+    """With ``REPRO_OBS`` unset, a traced run puts every span of the
+    sweep loop, the sample writer and the serving step into the
+    profiler's trace, each on the thread that did the work and nested
+    inside its parent."""
+    import gc
+
+    from repro.core import PredictSession
+    from repro.launch.serve import RecommendServer
+
+    monkeypatch.delenv("REPRO_OBS", raising=False)
+    gc_in = {}
+
+    def work():
+        _toy_train(tmp_path, callbacks=[lambda info: gc.collect()])
+        ps = PredictSession(str(tmp_path / "store"))
+        srv = RecommendServer(ps, slots=2, k=3)
+        user_rows = ps.user_rows
+
+        def rows(*a, **kw):
+            gc.collect()
+            return user_rows(*a, **kw)
+
+        monkeypatch.setattr(ps, "user_rows", rows)
+        for u in range(3):
+            srv.submit(user=u, exclude=[0])
+        srv.run()
+        gc_in["served"] = len(srv.done)
+
+    threads = _profiled(tmp_path, work)
+    assert gc_in["served"] == 3
+    (loop,) = [t for t in threads if any(s[2] == "sweep" for s in t)]
+    for child, parent in (("session/readback", "sweep"),
+                          ("ckpt/wait", "session/save"),
+                          ("ckpt/host_copy", "session/save"),
+                          ("predict/rows", "serve/step"),
+                          ("predict/mask", "serve/step"),
+                          ("predict/score", "serve/step"),
+                          ("predict/readback", "serve/step"),
+                          ("serve/finish", "serve/step")):
+        assert _inside(loop, child, parent), (child, parent)
+    names = {s[2] for s in loop}
+    assert {"session/accumulate", "session/callbacks",
+            "serve/admit"} <= names
+    # the collections forced in a callback and in the rows' gather
+    gcs = [s for s in loop if s[2] == "gc"]
+    for parent in ("session/callbacks", "predict/rows"):
+        assert any(p[0] <= g[0] and g[1] <= p[1] for g in gcs
+                   for p in loop if p[2] == parent), parent
+    # the sample writer's span is on the writer's own thread
+    assert "ckpt/save" not in names
+    assert any(s[2] == "ckpt/save" for t in threads if t is not loop
+               for s in t)
+
+
+def test_disabled_recorder_annotates_but_records_nothing(tmp_path,
+                                                         monkeypatch):
+    """Off, a span still reaches the profiler's trace; the recorder
+    reads no clock and keeps no event."""
+    import gc
+
+    from repro.obs import clock
+
+    def no_clock():
+        raise AssertionError("a disabled recorder read the clock")
+
+    monkeypatch.setattr(clock, "perf_counter", no_clock)
+    monkeypatch.setattr(clock, "monotonic", no_clock)
+    rec = Recorder(enabled=False)
+
+    def work():
+        with rec.gc_spans():
+            with rec.span("layer/phase", step=1):
+                gc.collect()
+
+    threads = _profiled(tmp_path, work)
+    (main,) = [t for t in threads if any(s[2] == "layer/phase" for s in t)]
+    assert _inside(main, "gc", "layer/phase")
+    assert rec.trace()["traceEvents"] == []
+    assert rec.metrics()["counters"] == {}
+
+
+def test_gc_spans_record_each_collection(tmp_path, monkeypatch):
+    """An enabled recorder keeps one ``gc`` event per collection during
+    ``Session.run`` and ``RecommendServer.run``, with its generation
+    and the objects collected."""
+    import gc
+
+    from repro.core import PredictSession
+    from repro.launch.serve import RecommendServer
+
+    was = gc.isenabled()
+    gc.disable()          # only the collections forced below
+    try:
+        rec = Recorder(enabled=True)
+        _toy_train(tmp_path, recorder=rec,
+                   callbacks=[lambda info: gc.collect(1)])
+        gcs = [e for e in rec.trace()["traceEvents"] if e["name"] == "gc"]
+        assert len(gcs) == 4                    # one per sweep
+        assert {e["args"]["generation"] for e in gcs} == {1}
+        assert all(e["args"]["collected"] >= 0 for e in gcs)
+
+        srv_rec = Recorder(enabled=True)
+        ps = PredictSession(str(tmp_path / "store"))
+        srv = RecommendServer(ps, slots=2, k=3, recorder=srv_rec)
+        gc.collect()                            # outside run: not kept
+        for u in range(4):
+            srv.submit(user=u)
+        monkeypatch.setattr(srv, "_finish", lambda s, f=srv._finish:
+                            (gc.collect(), f(s)))
+        srv.run()
+        assert sum(e["name"] == "gc" for e in
+                   srv_rec.trace()["traceEvents"]) == 4
+    finally:
+        if was:
+            gc.enable()
+    # the hooks are removed on exit
+    assert not any(getattr(cb, "__qualname__", "").startswith(
+        "Recorder.gc_spans") for cb in gc.callbacks)
+
+
+def test_gc_inside_a_locked_section_is_recorded():
+    """A collection can interrupt the recorder's own locked sections (a
+    sample writer's span, a histogram update); its span is recorded
+    there instead of deadlocking."""
+    import gc
+    import threading
+
+    rec = Recorder(enabled=True)
+
+    def work():
+        with rec.gc_spans():
+            with rec._lock:
+                gc.collect()
+            rec.trace()
+
+    t = threading.Thread(target=work, daemon=True)
+    t.start()
+    t.join(timeout=30)
+    assert not t.is_alive()
+    assert [e["name"] for e in rec.trace()["traceEvents"]] == ["gc"]
+
+
+def test_serve_step_span_carries_step_and_ids(tmp_path):
+    from repro.core import PredictSession
+    from repro.launch.serve import RecommendServer
+
+    _toy_train(tmp_path)
+    srv = RecommendServer(PredictSession(str(tmp_path / "store")),
+                          slots=2, k=3)
+    ids = [srv.submit(user=u) for u in range(3)]
+    srv.run()
+    steps = [e for e in srv.obs.trace()["traceEvents"]
+             if e["name"] == "serve/step"]
+    assert [e["args"]["step"] for e in steps] == [0, 1]
+    assert [e["args"]["ids"] for e in steps] == [ids[:2], ids[2:]]
+    assert [e["args"]["batch"] for e in steps] == [2, 1]
+
+
+def test_event_ring_drops_the_oldest_and_counts_them():
+    from repro.obs.recorder import MAX_EVENTS
+
+    assert MAX_EVENTS == 65_536
+    rec = Recorder(enabled=True)
+    for i in range(MAX_EVENTS + 10):
+        rec.complete("e", 0.0, end=0.0, i=i)
+    events = rec.trace()["traceEvents"]
+    assert len(events) == MAX_EVENTS
+    assert events[0]["args"]["i"] == 10
+    assert events[-1]["args"]["i"] == MAX_EVENTS + 9
+    assert rec.counter("obs.events_dropped") == 10.0
+
+
+@pytest.mark.parametrize("program", ["gibbs_step", "sharded_sweep"])
+def test_sweep_stages_are_named_in_the_compiled_module(program):
+    """The sweep's stages carry ``jax.named_scope`` names into the
+    compiled HLO's ``op_name`` metadata, whatever the functions are
+    called."""
+    import re
+
+    import jax
+
+    from repro.core import AdaptiveGaussian, ModelBuilder
+    from repro.core.gibbs import gibbs_step, init_state
+    from repro.core.sparse import random_sparse
+
+    mat, _, _ = random_sparse(3, (40, 24), 0.3, rank=3)
+    b = ModelBuilder(num_latent=4)
+    b.add_entity("u", 40)
+    b.add_entity("v", 24)
+    b.add_block("u", "v", mat, noise=AdaptiveGaussian())
+    model, data, _ = b.build()
+    state = init_state(model, data, 0)
+    scopes = ["gather", "gram", "solve", "hyper", "noise", "metrics",
+              "residuals"]
+    if program == "gibbs_step":
+        low = gibbs_step.lower(model, data, state)
+    else:
+        from repro.core.distributed import make_distributed_step
+        from repro.launch.mesh import make_mesh
+        step, ds, _ = make_distributed_step(
+            model, make_mesh((1,), ("data",)), data, state,
+            pipeline="eager")
+        low = step.lower(jax.device_put(data, ds), state)
+        scopes.append("exchange")
+    names = set(re.findall(r'op_name="([^"]*)"', low.compile().as_text()))
+    for scope in scopes:
+        assert any(f"/{scope}/" in n for n in names), scope
