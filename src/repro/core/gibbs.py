@@ -6,8 +6,8 @@ One ``gibbs_step`` performs, per entity in order:
      factor matrix ("sample hyper-parameters ... based on U/V"),
   2. resample the whole factor matrix from its conditional
      ("for all movies/users: update model") — one *batched* pass:
-     masked Gram + rhs (Pallas kernel or jnp oracle), batched Cholesky,
-     batched triangular solves, one fused N(0,1) draw,
+     masked Gram + rhs (Pallas kernel or jnp oracle), then one
+     lane-batched Cholesky draw per row (``ops.draw_branches``),
 
 then resamples every block's noise state from the residuals and reports
 train-RMSE metrics.
@@ -29,7 +29,7 @@ from .. import compat
 from ..kernels import ops
 from .blocks import DenseBlock, ModelDef
 from .noise import ProbitNoise
-from .priors import MacauPrior, SpikeAndSlabPrior, chol_solve
+from .priors import MacauPrior, SpikeAndSlabPrior
 from .sparse import SparseMatrix
 
 
@@ -299,13 +299,13 @@ def _sample_normal_factor(key, gram_shared, gram_rows, rhs, Lam_p, b_p,
         dz = triangular_solve(L, z.T, left_side=True, lower=True,
                               transpose_a=True).T
         return mean + dz
-    Lam = gram_rows + (gram_shared + Lam_p)[None, :, :] \
-        if gram_shared is not None else gram_rows + Lam_p[None, :, :]
-    L = cholesky(Lam)                                        # (N,K,K)
-    mean = chol_solve(L, b)
-    dz = triangular_solve(L, z[..., None], left_side=True, lower=True,
-                          transpose_a=True)[..., 0]
-    return mean + dz
+    # per-row precisions: the lane-batched kernel on a TPU.  Selected
+    # here, so the draw's device ops carry this function's frame (the
+    # stage tables under bench/stages/ read it)
+    lam = Lam_p if gram_shared is None else gram_shared + Lam_p
+    tpu, default = ops.draw_branches(b.shape[1])
+    return jax.lax.platform_dependent(gram_rows, lam, b, z, tpu=tpu,
+                                      default=default)
 
 
 def _sample_sns_factor(model: ModelDef, data: MFData, key,

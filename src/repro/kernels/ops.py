@@ -7,6 +7,15 @@ config.  The default is the XLA path; the tests exercise the Pallas
 path in interpret mode on CPU, ``tests/test_tpu_compile.py`` compiles
 it for a described TPU v5e, and ``chip_smoke.py`` runs it on the chip.
 
+The factor rows' Cholesky draw (``draw_branches``) is chosen by
+platform and shape, not by ``use_pallas``: on a TPU it runs the
+``chol_draw`` kernel, since XLA's batched Cholesky and triangular
+inverse there take one matrix at a time and are no real alternative;
+every other backend keeps ``lax.linalg`` (LAPACK on the CPU).  A K
+above ``CHOL_DRAW_MAX_K`` takes ``lax.linalg`` everywhere.  The choice
+is made per traced program; ``repro.obs.trace_counts()`` counts it as
+``solve.kernel_draws`` / ``solve.lax_draws``.  No option selects it.
+
 Kernel contracts
 ----------------
 Every kernel shipped from this package is registered in ``KERNELS``
@@ -32,7 +41,9 @@ from typing import Any, Callable, Mapping, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..obs import count_trace
 from . import ref
+from .chol_draw import LANES, chol_draw_pallas
 from .flash import flash_fwd_pallas
 from .gram import gram_pallas
 from .sddmm import sddmm_pallas
@@ -187,6 +198,68 @@ def sddmm(ug: jnp.ndarray, vg: jnp.ndarray, *, use_pallas: bool = False,
     return out[:E]
 
 
+# the largest K the draw kernel takes: its (K, K, 128) float32
+# precisions and the double-buffered (128 K, 128) Gram columns are
+# 65 MiB at K=256, about half of a v5e core's 128 MiB of VMEM; the next
+# width, 384, would need 120 MiB
+CHOL_DRAW_MAX_K = 256
+
+
+def chol_draw(gram_rows: jnp.ndarray, lam: jnp.ndarray, b: jnp.ndarray,
+              z: jnp.ndarray, *, interpret: bool | None = None):
+    """Lane-batched conditional draw; see kernels/chol_draw.py.
+
+    gram_rows (N, K, K), lam (K, K) added to every row's precision, b
+    and z (N, K) -> u (N, K) with u_n = Lambda_n^{-1} b_n + R_n^{-1} z_n,
+    Lambda_n = gram_rows_n + lam = R_n^T R_n: ``ref.chol_draw_ref``'s
+    mathematics.  The kernel reads the Gram rows as they lie and puts
+    the batch on the lanes itself.  K pads to a multiple of 8, or of
+    128 above 128 (zero Gram, identity in lam), and N to a multiple of
+    128 (zero Gram over the positive definite lam), b and z with zeros:
+    the padding solves to exact zeros and is sliced off.
+    """
+    interpret = _interpret(interpret)
+    N, K = b.shape
+    # K past one lane width goes over the kernel's column steps
+    mult = 8 if K <= LANES else LANES
+    gram = pad_to_blocks(gram_rows, {0: LANES, 1: mult, 2: mult})
+    n_p, kp = gram.shape[:2]
+    lam_p = pad_to_blocks(lam, {0: mult, 1: mult})
+    if kp != K:
+        pad = jnp.arange(kp) >= K
+        lam_p = lam_p + jnp.diag(pad.astype(lam_p.dtype))
+    bt = pad_to_blocks(b.T, {0: mult, 1: LANES})
+    zt = pad_to_blocks(z.T, {0: mult, 1: LANES})
+    u = chol_draw_pallas(gram.reshape(n_p * kp, kp), lam_p, bt, zt,
+                         interpret=interpret)
+    return u[:K, :N].T
+
+
+def _draw_on_tpu(gram_rows, lam, b, z):
+    return chol_draw(gram_rows, lam, b, z, interpret=False)
+
+
+# every backend but the TPU; a test that must run the kernel's branch
+# on the CPU points this at ``chol_draw`` in interpret mode
+_draw_elsewhere = ref.chol_draw_ref
+
+
+def draw_branches(k: int):
+    """The conditional draw's ``(tpu, default)`` branches for
+    ``jax.lax.platform_dependent`` at latent width ``k``, each taking
+    ``(gram_rows, lam, b, z)`` as ``chol_draw`` does.
+
+    Up to ``CHOL_DRAW_MAX_K`` the ``chol_draw`` kernel on a TPU and
+    ``lax.linalg`` elsewhere (module docstring); above it ``lax.linalg``
+    on both.  Each call counts one traced draw by the decision.
+    """
+    if k > CHOL_DRAW_MAX_K:
+        count_trace("solve.lax_draws")
+        return ref.chol_draw_ref, ref.chol_draw_ref
+    count_trace("solve.kernel_draws")
+    return _draw_on_tpu, _draw_elsewhere
+
+
 # ---------------------------------------------------------------------------
 # kernel registry: the statically-verified shape envelope
 # ---------------------------------------------------------------------------
@@ -237,6 +310,16 @@ def _topk_call_excl(k):
     def call(us, v, ex):
         return topk_score(us, v, k, exclude=ex, use_pallas=True)
     return call
+
+
+def _chol_draw_call(gram_rows, lam, b, z):
+    return chol_draw(gram_rows, lam, b, z)
+
+
+def _chol_draw_probe(label, n, k):
+    return KernelProbe(label, (_sds((n, k, k)), _sds((k, k)),
+                               _sds((n, k)), _sds((n, k))),
+                       _chol_draw_call)
 
 
 def _flash_call(**kw):
@@ -293,6 +376,18 @@ KERNELS = {
         ),
         vmem_budget=12 << 20,
         jit_fns=(topk_score_pallas,)),
+    "chol_draw": KernelSpec(
+        "chol_draw",
+        probes=(
+            _chol_draw_probe("production rows n32768 K128", 32_768, 128),
+            _chol_draw_probe("production cols n8192 K128", 8_192, 128),
+            _chol_draw_probe("uneven tail n300 K13", 300, 13),
+            _chol_draw_probe("widest, two column steps n256 K256",
+                             256, 256),
+        ),
+        # the double-buffered (128 K, 128) Gram columns and the
+        # (K, K, 128) precisions: 24.5 MiB at K=128, 65 MiB at K=256
+        vmem_budget=66 << 20),
     "flash": KernelSpec(
         "flash",
         probes=(

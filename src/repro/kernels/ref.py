@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.lax.linalg import cholesky, triangular_solve
 
 
 def gram_ref(vg: jnp.ndarray, val: jnp.ndarray, mask: jnp.ndarray):
@@ -46,6 +47,25 @@ def gram_ref(vg: jnp.ndarray, val: jnp.ndarray, mask: jnp.ndarray):
     gram = jnp.einsum("rtk,rtl->rkl", vg * m[..., None], vg)
     rhs = jnp.einsum("rtk,rt->rk", vg, w)
     return gram, rhs
+
+
+def chol_draw_ref(gram_rows: jnp.ndarray, lam: jnp.ndarray,
+                  b: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
+    """The conditional draw of a block of factor rows, through
+    ``lax.linalg``: the oracle of ``kernels/chol_draw.py`` and the path
+    every backend but the TPU takes.
+
+    gram_rows (N, K, K), lam (K, K) added to every row, b and z (N, K)
+    -> u (N, K): u_n = Lambda_n^{-1} b_n + L_n^{-T} z_n with
+    Lambda_n = gram_rows_n + lam = L_n L_n^T.
+    """
+    L = cholesky(gram_rows + lam[None, :, :])                # (N,K,K)
+    y = triangular_solve(L, b[..., None], left_side=True, lower=True)
+    mean = triangular_solve(L, y, left_side=True, lower=True,
+                            transpose_a=True)[..., 0]
+    dz = triangular_solve(L, z[..., None], left_side=True, lower=True,
+                          transpose_a=True)[..., 0]
+    return mean + dz
 
 
 def score_moments(u: jnp.ndarray, v: jnp.ndarray, n_samples: int):

@@ -11,10 +11,11 @@ from .metrics import (Histogram, METRICS_FORMAT, TRACE_FORMAT,
                       integer_buckets, latency_buckets, percentile_summary,
                       prometheus_text, write_json_atomic)
 from .recorder import Recorder, obs_enabled, resolve_recorder
+from .trace_counts import count_trace, trace_counts
 
 __all__ = [
     "Histogram", "METRICS_FORMAT", "TRACE_FORMAT", "Recorder", "clock",
-    "integer_buckets", "latency_buckets", "obs_enabled",
+    "count_trace", "integer_buckets", "latency_buckets", "obs_enabled",
     "percentile_summary", "prometheus_text", "resolve_recorder",
-    "write_json_atomic",
+    "trace_counts", "write_json_atomic",
 ]

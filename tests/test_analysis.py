@@ -415,13 +415,14 @@ def test_kernel_file_without_registry_is_an_error(tmp_path):
 
 @pytest.mark.slow
 def test_shipped_kernel_registry_proves_clean():
-    """The real tree: all four kernels, all shipped block configs —
+    """The real tree: all five kernels, all shipped block configs —
     race-free, in bounds, fp32-accumulating, inside VMEM budget."""
     from repro.analysis import check_kernels, vmem_report
     findings = check_kernels()
     assert findings == [], "\n".join(f.format() for f in findings)
     report = vmem_report()
-    assert sorted(report) == ["flash", "gram", "sddmm", "topk_score"]
+    assert sorted(report) == ["chol_draw", "flash", "gram", "sddmm",
+                              "topk_score"]
     for name, r in report.items():
         assert r["ok"], (name, r)
         assert 0 < r["peak_bytes"] <= r["budget_bytes"], (name, r)

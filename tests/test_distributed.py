@@ -716,6 +716,22 @@ _HLO_WIDENED_SCRIPT = textwrap.dedent("""
 """)
 
 
+# The same parity with the factor draw on the chol_draw kernel's branch
+# (interpret mode): the sharded sweep draws each shard's rows with the
+# kernel, the single device all rows at once.
+_KERNEL_PARITY_SCRIPT = _PARITY_SCRIPT.replace(
+    "import jax.numpy as jnp\n", textwrap.dedent("""\
+    import jax.numpy as jnp
+    import functools
+    from repro.kernels import ops
+    from repro.obs import trace_counts
+    ops._draw_elsewhere = functools.partial(ops.chol_draw, interpret=True)
+    """), 1).replace('print("OK")', textwrap.dedent("""\
+    assert trace_counts().get("solve.kernel_draws", 0) >= 6, trace_counts()
+    assert "solve.lax_draws" not in trace_counts()
+    print("OK")"""))
+
+
 def _run(script):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
@@ -749,6 +765,14 @@ def test_resolve_pipeline_validates_choices(monkeypatch):
 @pytest.mark.slow
 def test_distributed_gibbs_matches_single_device():
     _run(_PARITY_SCRIPT)
+
+
+@pytest.mark.slow
+def test_distributed_kernel_draw_matches_single_device():
+    """The sharded sweep against the single device with every factor
+    draw on the kernel's branch, at the lax path's 2e-4 parity."""
+    assert "ops._draw_elsewhere" in _KERNEL_PARITY_SCRIPT
+    _run(_KERNEL_PARITY_SCRIPT)
 
 
 @pytest.mark.slow

@@ -11,7 +11,9 @@ The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library, and test workers
 import every test file.  Keep all such compiles in this one file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +32,8 @@ KIND = "TPU v5 lite"
 # the 1/32 row share of bmf_chembl: rows, cols, K, nnz per row, and the
 # padded column width fixed_degree(0, ...) gives at that size
 R, C, K, T, W = 32_768, 8_192, 128, 64, 328
-PROBES = [(name, p) for name in ("gram", "sddmm", "topk_score")
+PROBES = [(name, p)
+          for name in ("gram", "sddmm", "topk_score", "chol_draw")
           for p in ops.KERNELS[name].probes]
 
 
@@ -106,10 +109,10 @@ def test_kernel_main_path_widths_compile_for_v5e(name, fn, shapes,
     assert "tpu_custom_call" in _kernel_hlo(fn, args)
 
 
-def test_xla_sweep_one_32nd_share_fits_one_v5e(one_chip):
+@pytest.fixture(scope="module")
+def sweep_one_32nd_share(one_chip):
     """One Gibbs sweep of the 1/32 bmf_chembl share (the XLA path that
-    ``Session`` runs by default) compiles for one chip and fits its
-    HBM."""
+    ``Session`` runs by default), compiled for one chip."""
     model = ModelDef(
         (EntityDef("compound", R, NormalPrior(K)),
          EntityDef("protein", C, NormalPrior(K))),
@@ -126,7 +129,31 @@ def test_xla_sweep_one_32nd_share_fits_one_v5e(one_chip):
                        _sds((E,), jnp.int32), shape=(R, C))
     data = _on(one_chip, MFData((mat,), (None, None)))
     state = _on(one_chip, jax.eval_shape(lambda: init_state(model, data)))
-    mem = gibbs_step.lower(model, data, state).compile().memory_analysis()
+    return gibbs_step.lower(model, data, state).compile()
+
+
+def test_xla_sweep_one_32nd_share_fits_one_v5e(sweep_one_32nd_share):
+    """The sweep fits one chip's HBM."""
+    mem = sweep_one_32nd_share.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes)
     assert used < peaks_for(KIND).hbm_bytes
+
+
+def test_sweep_draws_factor_rows_with_the_kernel_on_v5e(
+        sweep_one_32nd_share):
+    """On the chip the factor draw is the ``chol_draw`` Mosaic kernel,
+    once per entity, and no batched Cholesky or triangular inverse is
+    left: the (K, K) ones of the hyper-parameter draws stay XLA's."""
+    text = sweep_one_32nd_share.as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and re.match(r"\s*%chol_draw(\.\d+)? = ", line)]
+    assert len(kernels) == 2
+    linalg = re.findall(
+        r"= (f32\[[\d,]*\])\S* custom-call\([^\n]*custom_call_target="
+        r'"(Cholesky|InvertDiagBlocksLowerTriangular)"', text)
+    assert linalg, "the hyper-parameter draws' Cholesky calls are missing"
+    for shape, target in linalg:
+        dims = [int(d) for d in shape[4:-1].split(",")]
+        assert math.prod(dims[:-2]) == 1, (target, shape)
