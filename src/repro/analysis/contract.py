@@ -9,7 +9,7 @@ ring pipeline made exact:
                           entity in eager mode, **zero** in ring mode;
 * ``collective_permutes`` — ring hops: ``E * (S - 1)`` in ring mode;
 * ``all_reduces``       — hyper-moment + metric psums: per entity
-                          2 (Normal), 4 (Macau), 2 (SpikeAndSlab),
+                          2 (Normal), 3 (Macau), 2 (SpikeAndSlab),
                           0 (FixedNormal), plus 2 scalar psums per
                           block (SSE, nnz);
 * ``max_reduce_elems``  — largest all-reduce payload in elements
@@ -80,8 +80,9 @@ def _prior_reduce_profile(prior) -> Tuple[int, int]:
     K = getattr(prior, "num_latent", 0)
     if isinstance(prior, MacauPrior):
         D = prior.num_features
-        # sum_U (K), moment (K,K), side moment (D,K), side norm (D)
-        return 4, max(K * K, D * K, D, K)
+        # sum_U (K), moment (K,K), side moment (D,K); the side's Gram
+        # and column sums are static data (gibbs.side_statistics)
+        return 3, max(K * K, D * K, K)
     if isinstance(prior, SpikeAndSlabPrior):
         return 2, K                    # slab mass (K) + counts (K)
     if isinstance(prior, FixedNormalPrior):

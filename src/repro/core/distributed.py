@@ -39,7 +39,7 @@ over the production mesh (``compat.shard_map`` — version-portable):
     that term wins on the target).
 * the Normal-Wishart hyper-sample needs global factor moments: those
   reduce over the row shards with K- and K^2-sized ``psum`` payloads
-  (D-sized for the Macau link terms) and are then resampled as an
+  (D x K-sized for the Macau link term) and are then resampled as an
   identical replicated computation on every shard;
 * dense blocks shard the same way: both stored orientations
   (``DenseBlock.X``/``XT``) are row-sharded along their leading axis,
@@ -51,9 +51,11 @@ over the production mesh (``compat.shard_map`` — version-portable):
   (``gibbs.row_uniforms`` threaded through ``ProbitNoise.augment`` via
   ``row_offset``) — the compound-activity classification workload of
   the paper runs the explicit sweep, not the pjit fallback;
-* the Macau side-Gramian ``FtF = side^T side`` is STATIC data: it is
-  computed once at ``make_distributed_step`` placement time and passed
-  in replicated, so the per-sweep hyper path carries no (D, D) psum;
+* the Macau side statistics (the side-Gramian ``FtF = side^T side``
+  and the side's column sums) are STATIC data: computed once when the
+  data is built (``gibbs.make_data``, shared with the single-device
+  sweep) and placed replicated, so the per-sweep hyper path carries no
+  (D, D) psum and no psum of the column sums;
 * spike-and-slab priors (the GFA composition, paper Table 1
   "Normal + SnS") run the same schedule: the coordinate-wise q/l
   moments are row-local given the gathered fixed factor, so the
@@ -100,7 +102,7 @@ from .. import compat
 from .blocks import DenseBlock, ModelDef
 from .gibbs import (MFData, MFState, _dense_chunk_contrib, _dense_contrib,
                     _sample_normal_factor, _sample_sns_factor,
-                    _sparse_contrib, gibbs_step)
+                    _sparse_contrib, entity_side_stats, gibbs_step)
 from .noise import AdaptiveGaussian, FixedGaussian, ProbitNoise
 from .priors import (FixedNormalPrior, MacauPrior, NormalPrior,
                      SpikeAndSlabPrior)
@@ -202,7 +204,8 @@ def data_specs(model: ModelDef, mesh: Mesh, data: MFData) -> MFData:
     blocks = tuple(for_block(b) for b in data.blocks)
     sides = tuple(None if s is None else _fit_rows_spec(mesh, s)
                   for s in data.sides)
-    return MFData(blocks, sides)
+    stats = jax.tree.map(lambda x: P(), data.side_stats)
+    return MFData(blocks, sides, stats)
 
 
 def _with_mesh(mesh: Mesh, tree):
@@ -358,26 +361,30 @@ def _streamable(model: ModelDef, bi: int, e: int) -> bool:
             and max(blk.row_entity, blk.col_entity) != e)
 
 
-def _psum_hyper(model: ModelDef, e: int, key, u, hyper, side, axes,
-                ftf=None):
+def _psum_hyper(model: ModelDef, e: int, key, u, hyper, data: MFData,
+                axes):
     """Hyper-sample from psummed moments — replicated-identical output.
 
     The collective payloads are K (factor sum), K^2 (factor Gramian)
-    and, for Macau link terms, D/DxK — negligible next to the factor
-    all-gathers.  The Macau (D, D) side-Gramian ``ftf`` is NOT psummed
-    here: it is static data, computed once at placement time in
-    ``make_distributed_step`` and passed in replicated.
+    and, for Macau link terms, DxK — negligible next to the factor
+    all-gathers.  The Macau side statistics (the (D, D) side-Gramian
+    and the side's column sums) are NOT psummed here: they are static
+    data, computed once when the data is built
+    (``gibbs.side_statistics``) and placed replicated.
     """
     prior = model.entities[e].prior
     N = model.entities[e].n_rows
     psum = partial(jax.lax.psum, axis_name=axes)
     if isinstance(prior, MacauPrior):
-        Uc = u - side @ hyper["beta"]
+        side = data.sides[e]
+        FtF, s_side = entity_side_stats(data, e)
+        with jax.named_scope("link"):
+            Uc = u - side @ hyper["beta"]
+            StF = psum(side.T @ u)
         return prior.sample_hyper_moments(
             key, hyper,
             F_sum=psum(Uc.sum(axis=0)), F_cov=psum(Uc.T @ Uc), n_rows=N,
-            StF=psum(side.T @ u), s_side=psum(side.sum(axis=0)),
-            FtF=ftf)
+            StF=StF, s_side=s_side, FtF=FtF)
     if isinstance(prior, NormalPrior):
         return prior.sample_hyper_moments(
             key, hyper, F_sum=psum(u.sum(axis=0)), F_cov=psum(u.T @ u),
@@ -394,7 +401,7 @@ def _psum_hyper(model: ModelDef, e: int, key, u, hyper, side, axes,
 
 
 def _sharded_sweep(model: ModelDef, axes: Tuple[str, ...],
-                   sizes: Tuple[int, ...], pipeline: str, ftf,
+                   sizes: Tuple[int, ...], pipeline: str,
                    data: MFData, state: MFState):
     """One full Gibbs sweep, executed per-shard inside shard_map.
 
@@ -405,9 +412,8 @@ def _sharded_sweep(model: ModelDef, axes: Tuple[str, ...],
     one fixed-factor exchange per half-sweep (a blocking ``all_gather``
     in the ``"eager"`` pipeline, ``S - 1`` double-buffered ``ppermute``
     hops in ``"ring"`` — see the module docstring), K/K^2 psums for the
-    hyper moments, scalar psums for residual SSE/nnz.  ``ftf`` holds
-    the per-entity Macau side-Gramians, precomputed and replicated
-    (None for non-Macau entities).
+    hyper moments, scalar psums for residual SSE/nnz.  The Macau side
+    statistics travel in ``data`` (``MFData.side_stats``), replicated.
     """
     S = int(np.prod(sizes))
     shard = _shard_index(axes, sizes)
@@ -467,8 +473,7 @@ def _sharded_sweep(model: ModelDef, axes: Tuple[str, ...],
 
         # 1. hyper-parameters from psummed global moments
         with jax.named_scope("hyper"):
-            hyper = _psum_hyper(model, e, k_hyp, u, hypers[e], side, axes,
-                                ftf=ftf[e])
+            hyper = _psum_hyper(model, e, k_hyp, u, hypers[e], data, axes)
 
         # 2. this shard's factor rows from their conditional
         prior = ent.prior
@@ -639,28 +644,6 @@ def _sharded_sweep(model: ModelDef, axes: Tuple[str, ...],
     return new_state, metrics
 
 
-def _macau_ftf(model: ModelDef, data: MFData):
-    """Per-entity Macau side-Gramians ``side^T side`` — STATIC data.
-
-    Computed ONCE here (placement time) so the per-sweep loop carries
-    no (D, D) psum; asserted on the HLO in tests/test_distributed.py.
-    Abstract (ShapeDtypeStruct) sides — the dry-run path, which only
-    lowers — produce abstract Gramians.
-    """
-    out = []
-    for e, ent in enumerate(model.entities):
-        side = data.sides[e]
-        if not isinstance(ent.prior, MacauPrior) or side is None:
-            out.append(None)
-        elif isinstance(side, jax.ShapeDtypeStruct):
-            D = side.shape[1]
-            out.append(jax.ShapeDtypeStruct((D, D), jnp.float32))
-        else:
-            side = jnp.asarray(side, jnp.float32)
-            out.append(side.T @ side)
-    return tuple(out)
-
-
 def _validate_chain_axis(mesh: Mesh, chains: int,
                          chain_axis: Optional[str]) -> None:
     if chain_axis is None:
@@ -715,31 +698,21 @@ def make_multi_chain_step(model: ModelDef, mesh: Mesh, data: MFData,
     if distributed_supported(model, mesh, data):
         axes = _axes_in(mesh)
         sizes = compat.mesh_axis_sizes(mesh, axes)
-        ftf = _macau_ftf(model, data)
-        ftf_specs = jax.tree.map(lambda x: P(), ftf)
 
-        def sweep_chains(ftf_, data_, stacked_):
+        def sweep_chains(data_, stacked_):
             return jax.lax.map(
                 lambda st: _sharded_sweep(model, axes, sizes, pipeline,
-                                          ftf_, data_, st),
+                                          data_, st),
                 stacked_)
 
         body = compat.shard_map(
             sweep_chains,
             mesh=mesh,
-            in_specs=(ftf_specs,
-                      data_specs(model, mesh, data),
-                      sss),
+            in_specs=(data_specs(model, mesh, data), sss),
             out_specs=(sss, mspec),
             check=False)
-        jfn = jax.jit(body,
-                      in_shardings=(_with_mesh(mesh, ftf_specs), ds, ss),
-                      out_shardings=(ss, NamedSharding(mesh, mspec)))
-
-        def fn(data, state):
-            return jfn(ftf, data, state)
-
-        fn.lower = lambda data, state: jfn.lower(ftf, data, state)
+        fn = jax.jit(body, in_shardings=(ds, ss),
+                     out_shardings=(ss, NamedSharding(mesh, mspec)))
     else:
         fn = jax.jit(
             lambda data_, stacked_: jax.lax.map(
@@ -769,9 +742,8 @@ def make_distributed_step(model: ModelDef, mesh: Mesh, data: MFData,
     travels — the sampled chain is pinned to the eager one by the
     ring-vs-eager parity and golden-chain tests.
 
-    ``step_fn(data, state)`` closes over the precomputed Macau
-    side-Gramians (replicated) and exposes ``.lower(data, state)``
-    exactly like a bare ``jax.jit`` result.
+    ``step_fn`` is a bare ``jax.jit`` result: ``step_fn(data, state)``,
+    ``step_fn.lower(data, state)``.
     """
     pipeline = resolve_pipeline(pipeline)
     ss = state_shardings(model, mesh, state)
@@ -779,24 +751,15 @@ def make_distributed_step(model: ModelDef, mesh: Mesh, data: MFData,
     if distributed_supported(model, mesh, data):
         axes = _axes_in(mesh)
         sizes = compat.mesh_axis_sizes(mesh, axes)
-        ftf = _macau_ftf(model, data)
-        ftf_specs = jax.tree.map(lambda x: P(), ftf)
         body = compat.shard_map(
             partial(_sharded_sweep, model, axes, sizes, pipeline),
             mesh=mesh,
-            in_specs=(ftf_specs,
-                      data_specs(model, mesh, data),
+            in_specs=(data_specs(model, mesh, data),
                       state_specs(model, mesh, state)),
             out_specs=(state_specs(model, mesh, state), P()),
             check=False)
-        jfn = jax.jit(body,
-                      in_shardings=(_with_mesh(mesh, ftf_specs), ds, ss),
-                      out_shardings=(ss, replicated(mesh)))
-
-        def fn(data, state):
-            return jfn(ftf, data, state)
-
-        fn.lower = lambda data, state: jfn.lower(ftf, data, state)
+        fn = jax.jit(body, in_shardings=(ds, ss),
+                     out_shardings=(ss, replicated(mesh)))
     else:
         fn = jax.jit(
             partial(gibbs_step, model),

@@ -48,6 +48,56 @@ class MFData(NamedTuple):
 
     blocks: Tuple[Any, ...]             # SparseMatrix | DenseBlock
     sides: Tuple[Optional[jnp.ndarray], ...]   # per entity side info
+    # per entity (side^T side, column sums of side), None without a
+    # side (``side_statistics``); ``make_data`` fills it
+    side_stats: Optional[Tuple[Any, ...]] = None
+
+
+def side_statistics(sides) -> Tuple[Any, ...]:
+    """Per-entity static statistics of the side information:
+    ``(side^T side (D, D), column sums (D,))``, None where an entity
+    has no side.
+
+    The side matrix never changes along the chain, so both are computed
+    once, when the data is built, at full float32 precision; neither
+    sweep (``gibbs_step``, ``distributed._sharded_sweep``) forms a
+    (D, D) product of the side matrix or reduces it over rows.
+    Abstract (ShapeDtypeStruct) sides — the dry-run path, which only
+    lowers — give abstract statistics.
+    """
+    out = []
+    for side in sides:
+        if side is None:
+            out.append(None)
+        elif isinstance(side, jax.ShapeDtypeStruct):
+            D = side.shape[1]
+            out.append((jax.ShapeDtypeStruct((D, D), jnp.float32),
+                        jax.ShapeDtypeStruct((D,), jnp.float32)))
+        else:
+            side = jnp.asarray(side, jnp.float32)
+            out.append((jnp.matmul(side.T, side,
+                                   precision=jax.lax.Precision.HIGHEST),
+                        side.sum(axis=0)))
+    return tuple(out)
+
+
+def make_data(blocks, sides) -> MFData:
+    """``MFData`` with the side statistics of its ``sides``: how data
+    with side information is built (``ModelBuilder.build``, the
+    dry-run)."""
+    return MFData(tuple(blocks), tuple(sides), side_statistics(sides))
+
+
+def entity_side_stats(data: MFData, e: int):
+    """Entity ``e``'s side statistics; data built without them (a bare
+    ``MFData(blocks, sides)``) is refused rather than have the sweep
+    form the side Gram."""
+    stats = None if data.side_stats is None else data.side_stats[e]
+    if stats is None:
+        raise ValueError(
+            f"entity {e} has side information but the data carries no "
+            "side statistics: build it with gibbs.make_data")
+    return stats
 
 
 def init_state(model: ModelDef, data: MFData, seed: int = 0,
@@ -478,7 +528,8 @@ def _entity_update(model: ModelDef, data: MFData, key, e: int,
     # 1. hyper-parameters from the current factor (Algorithm 1 line 2/5)
     with jax.named_scope("hyper"):
         if isinstance(prior, MacauPrior):
-            hyper = prior.sample_hyper(k_hyp, u, hypers[e], side=side)
+            hyper = prior.sample_hyper(k_hyp, u, hypers[e], side,
+                                       entity_side_stats(data, e))
         else:
             hyper = prior.sample_hyper(k_hyp, u, hypers[e])
 

@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.lax.linalg import cholesky, triangular_solve
 
+from ..obs import count_trace
+
 
 # ---------------------------------------------------------------------------
 # shared linear-algebra helpers
@@ -208,23 +210,21 @@ class MacauPrior:
         h["beta_prec"] = jnp.asarray(self.beta_precision, jnp.float32)
         return h
 
-    def sample_hyper(self, key, F, hyper, side=None, FtF=None, **mom):
+    def sample_hyper(self, key, F, hyper, side, stats):
         """NW update on (U - F beta), then the beta conditional.
 
-        side (N, D): feature matrix.  FtF (D, D): precomputed side^T side
-        (static, may be psummed by the distributed caller).
+        side (N, D): feature matrix.  ``stats``: its static statistics
+        ``(side^T side (D, D), column sums (D,))``, computed once when
+        the data is built (``gibbs.side_statistics``).
         """
-        assert side is not None
-        U_centered = F - side @ hyper["beta"]
-        stats = dict(
-            F_sum=mom.get("F_sum", U_centered.sum(axis=0)),
-            F_cov=mom.get("F_cov", U_centered.T @ U_centered),
-            n_rows=mom.get("n_rows", F.shape[0]),
-            StF=mom.get("StF", side.T @ F),
-            s_side=mom.get("s_side", side.sum(axis=0)),
-            FtF=side.T @ side if FtF is None else FtF,
-        )
-        return self.sample_hyper_moments(key, hyper, **stats)
+        FtF, s_side = stats
+        with jax.named_scope("link"):
+            U_centered = F - side @ hyper["beta"]
+            StF = side.T @ F
+        return self.sample_hyper_moments(
+            key, hyper, F_sum=U_centered.sum(axis=0),
+            F_cov=U_centered.T @ U_centered, n_rows=F.shape[0], StF=StF,
+            s_side=s_side, FtF=FtF)
 
     def sample_hyper_moments(self, key, hyper, *, F_sum, F_cov, n_rows,
                              StF, s_side, FtF):
@@ -233,41 +233,45 @@ class MacauPrior:
         ``F_sum``/``F_cov`` are the moments of the *centered* factor
         U - side @ beta; ``StF`` = side^T U (D, K), ``s_side`` =
         column sums of side (D,), ``FtF`` = side^T side (D, D).  The
-        distributed sweep psums each of these over row shards; the rest
-        of the update is replicated K/D-sized linear algebra.
+        distributed sweep psums the first three over row shards (the
+        last two are static, ``gibbs.side_statistics``); the rest of the
+        update is replicated K/D-sized linear algebra.
         """
         k_nw, k_b, k_prec = jax.random.split(key, 3)
         h = self._normal.sample_hyper_moments(k_nw, hyper, F_sum=F_sum,
                                               F_cov=F_cov, n_rows=n_rows)
 
-        # beta | U, Lambda  ~ MN(mean, A^{-1}, Lambda^{-1}),
-        # A = side^T side + beta_prec * I
-        D, K = self.num_features, self.num_latent
-        A = FtF + hyper["beta_prec"] * jnp.eye(D, dtype=jnp.float32)
-        La = cholesky(A)
-        # side^T (U - mu 1^T) decomposed so shards only contribute sums
-        FtU = StF - jnp.outer(s_side, h["mu"])  # (D, K)
-        y = triangular_solve(La, FtU, left_side=True, lower=True)
-        mean_b = triangular_solve(La, y, left_side=True, lower=True,
+        # the direct link solve: one (D, D) Cholesky per traced program
+        count_trace("link.direct_solves")
+        with jax.named_scope("link"):
+            # beta | U, Lambda  ~ MN(mean, A^{-1}, Lambda^{-1}),
+            # A = side^T side + beta_prec * I
+            D, K = self.num_features, self.num_latent
+            A = FtF + hyper["beta_prec"] * jnp.eye(D, dtype=jnp.float32)
+            La = cholesky(A)
+            # side^T (U - mu 1^T) decomposed so shards only contribute sums
+            FtU = StF - jnp.outer(s_side, h["mu"])  # (D, K)
+            y = triangular_solve(La, FtU, left_side=True, lower=True)
+            mean_b = triangular_solve(La, y, left_side=True, lower=True,
+                                      transpose_a=True)
+            # sample: mean + La^{-T} Z Llam^{-1}
+            Z = jax.random.normal(k_b, (D, K), dtype=jnp.float32)
+            Zr = triangular_solve(La, Z, left_side=True, lower=True,
                                   transpose_a=True)
-        # sample: mean + La^{-T} Z Llam^{-1}
-        Z = jax.random.normal(k_b, (D, K), dtype=jnp.float32)
-        Zr = triangular_solve(La, Z, left_side=True, lower=True,
-                              transpose_a=True)
-        Llam = cholesky(h["Lambda"])
-        beta = mean_b + _mn_col_mix(Zr, Llam)
+            Llam = cholesky(h["Lambda"])
+            beta = mean_b + _mn_col_mix(Zr, Llam)
 
-        # lambda_beta ~ Gamma conditional (Macau eq. for the link precision)
-        if self.sample_beta_precision:
-            # beta has D*K entries; weighted by Lambda across components:
-            bl = beta @ h["Lambda"] @ beta.T
-            sse = jnp.trace(bl)
-            a_post = 0.5 * (D * K) + 1.0
-            b_post = 0.5 * sse + 1.0
-            prec = jax.random.gamma(k_prec, a_post) / b_post
-            h["beta_prec"] = prec.astype(jnp.float32)
-        else:
-            h["beta_prec"] = hyper["beta_prec"]
+            # lambda_beta ~ Gamma conditional (Macau eq. for the link
+            # precision): beta's D*K entries weighted by Lambda across
+            # components, tr(beta Lambda beta^T) without the (D, D) product
+            if self.sample_beta_precision:
+                sse = jnp.sum((beta @ h["Lambda"]) * beta)
+                a_post = 0.5 * (D * K) + 1.0
+                b_post = 0.5 * sse + 1.0
+                prec = jax.random.gamma(k_prec, a_post) / b_post
+                h["beta_prec"] = prec.astype(jnp.float32)
+            else:
+                h["beta_prec"] = hyper["beta_prec"]
         h["beta"] = beta
         return h
 
@@ -277,8 +281,9 @@ class MacauPrior:
     def mean_term(self, hyper, n_rows: int, side=None) -> jnp.ndarray:
         """(N, K): Lambda @ (mu + beta^T f_i) per row."""
         assert side is not None
-        m = hyper["mu"][None, :] + side @ hyper["beta"]
-        return m @ hyper["Lambda"].T
+        with jax.named_scope("link"):
+            m = hyper["mu"][None, :] + side @ hyper["beta"]
+            return m @ hyper["Lambda"].T
 
     def predict_factor(self, hyper, F_new) -> jnp.ndarray:
         """Latent rows for UNSEEN entities through the sampled link.

@@ -57,7 +57,7 @@ from .diagnostics import (Diagnostics, compute_diagnostics,
                           save_diagnostics)
 from .gibbs import (MFData, MFState, gibbs_step, init_chain_states,
                     init_state, multi_chain_step_jit, stack_states,
-                    unstack_state)
+                    make_data, unstack_state)
 from .noise import AdaptiveGaussian, FixedGaussian, ProbitNoise
 from .predict import PredictAccumulator, TestSet, make_test_set
 from .priors import (FixedNormalPrior, MacauPrior, NormalPrior,
@@ -451,7 +451,7 @@ class ModelBuilder:
                          self.bf16_gather)
         sides = tuple(None if s is None else jnp.asarray(s)
                       for *_, s in self._entities)
-        data = MFData(tuple(p for _, _, p, _, _ in self._blocks), sides)
+        data = make_data((p for _, _, p, _, _ in self._blocks), sides)
         tests = {bi: ts for bi, (*_, ts) in enumerate(self._blocks)
                  if ts is not None}
         return model, data, tests

@@ -17,8 +17,8 @@ meshes, with the same roofline extraction.
 Cells:
     bmf_chembl     1,048,576 x 8,192, K=128, ~67M observed entries
     macau_chembl   + 2048-bit ECFP side info on the compound axis
-                   (the side-Gramian FtF is hoisted to placement time —
-                   no per-sweep (D, D) psum)
+                   (the side-Gramian FtF and column sums are built
+                   with the data — no per-sweep (D, D) or D psum)
     probit_chembl  binary activity classification (paper §4): same
                    shape, ProbitNoise with counter-based truncated-
                    normal augmentation — runs the explicit sharded
@@ -140,7 +140,7 @@ def abstract_data(cell: MFCell):
     """MFData of ShapeDtypeStructs at full production size."""
     from ..core.blocks import DenseBlock
     from ..core.sparse import PaddedRows, SparseMatrix
-    from ..core.gibbs import MFData
+    from ..core.gibbs import make_data
 
     if cell.gfa_dims:
         N = cell.n_rows
@@ -148,14 +148,14 @@ def abstract_data(cell: MFCell):
             DenseBlock(_sds((N, D), F32), _sds((N, D), F32),
                        _sds((D, N), F32), _sds((D, N), F32), fully=True)
             for D in cell.gfa_dims)
-        return MFData(blks, (None,) * (1 + len(cell.gfa_dims)))
+        return make_data(blks, (None,) * (1 + len(cell.gfa_dims)))
 
     if cell.dense:
         R, C = cell.n_rows, cell.n_cols
         blk = DenseBlock(_sds((R, C), F32), _sds((R, C), F32),
                          _sds((C, R), F32), _sds((C, R), F32),
                          fully=True)
-        return MFData((blk,), (None, None))
+        return make_data((blk,), (None, None))
 
     rows = PaddedRows(_sds((cell.n_rows, cell.row_nnz), I32),
                       _sds((cell.n_rows, cell.row_nnz), F32),
@@ -172,7 +172,7 @@ def abstract_data(cell: MFCell):
                        shape=(cell.n_rows, cell.n_cols))
     side = _sds((cell.n_rows, cell.side_feats), F32) \
         if cell.side_feats else None
-    return MFData((mat,), (side, None))
+    return make_data((mat,), (side, None))
 
 
 def build_model(cell: MFCell, variant: str):

@@ -154,7 +154,7 @@ ZOO = {
     "gaussian": (_two_entity(), 2, 6, K * K, "f32"),
     "probit": (_two_entity(noise=ProbitNoise()), 2, 6, K * K, "f32"),
     "bf16": (_two_entity(bf16=True), 2, 6, K * K, "bf16"),
-    "macau": (_two_entity(row_prior=MacauPrior(K, 12)), 2, 8,
+    "macau": (_two_entity(row_prior=MacauPrior(K, 12)), 2, 7,
               12 * K, "f32"),
     "gfa": (_gfa_model(), 3, 8, K, "f32"),
 }

@@ -29,7 +29,7 @@ spike-and-slab priors (counter-based ``row_bernoulli`` inclusions +
 per-component-folded slab normals — the GFA composition), and the HLO
 checks pin one fixed-factor all-gather per half-sweep for those paths
 too, plus ZERO per-sweep Macau ``FtF`` psums (the (D, D) side-Gramian
-is hoisted to placement time) and ZERO per-component SnS collectives
+is static data, built with the data) and ZERO per-component SnS collectives
 (two K-sized hyper psums per view are the entire SnS budget).
 
 All HLO pins are expressed through ``repro.analysis.contract``: each
@@ -370,6 +370,7 @@ _RING_PARITY_SCRIPT = textwrap.dedent("""
     from repro.core.priors import (FixedNormalPrior, MacauPrior,
                                    NormalPrior, SpikeAndSlabPrior)
     from repro.core.sparse import random_sparse
+    from repro.core.gibbs import make_data
     from repro.launch.mesh import make_mesh
 
     K = 8
@@ -440,7 +441,7 @@ _RING_PARITY_SCRIPT = textwrap.dedent("""
     parity("macau",
            two_entity(FixedGaussian(5.0), True,
                       row_prior=MacauPrior(K, Dside)),
-           MFData((mat,), (side, None)), bitwise=True)
+           make_data((mat,), (side, None)), bitwise=True)
 
     # dense blocks chunk-accumulate their moments into the ring hops
     # (the overlap), which reorders the f32 sums -> 2e-4, not bitwise
@@ -502,6 +503,7 @@ _RING_HLO_SCRIPT = textwrap.dedent("""
     from repro.core.priors import (FixedNormalPrior, MacauPrior,
                                    NormalPrior, SpikeAndSlabPrior)
     from repro.core.sparse import random_sparse
+    from repro.core.gibbs import make_data
     from repro.launch.mesh import make_mesh
 
     K, Dside = 8, 12
@@ -546,7 +548,7 @@ _RING_HLO_SCRIPT = textwrap.dedent("""
             ModelDef(ents(MacauPrior(K, Dside)),
                      (BlockDef(0, 1, FixedGaussian(5.0), sparse=True),),
                      K),
-            MFData((mat,), (side, None))),
+            make_data((mat,), (side, None))),
         "dense_full": (
             ModelDef(ents(NormalPrior(K)),
                      (BlockDef(0, 1, FixedGaussian(5.0), sparse=False),),
@@ -650,6 +652,7 @@ _HLO_WIDENED_SCRIPT = textwrap.dedent("""
                                         make_distributed_step)
     from repro.core.priors import MacauPrior, NormalPrior
     from repro.core.sparse import random_sparse
+    from repro.core.gibbs import make_data
     from repro.launch.mesh import make_mesh
 
     K, D = 8, 12          # D != K so the FtF shape is unambiguous
@@ -685,7 +688,7 @@ _HLO_WIDENED_SCRIPT = textwrap.dedent("""
             ModelDef(ents(MacauPrior(K, D)),
                      (BlockDef(0, 1, FixedGaussian(5.0), sparse=True),),
                      K),
-            MFData((smat,), (side, None))),
+            make_data((smat,), (side, None))),
     }
 
     for name, (model, data) in cases.items():

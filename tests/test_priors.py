@@ -3,6 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.gibbs import side_statistics
 from repro.core.priors import (MacauPrior, NormalPrior,
                                SpikeAndSlabPrior, chol_solve,
                                sample_mvn_from_precision, sample_wishart)
@@ -91,9 +92,10 @@ def test_macau_beta_recovers_planted_link():
     prior = MacauPrior(K, D, sample_beta_precision=False,
                        beta_precision=1.0)
     h = prior.init(jax.random.PRNGKey(0), N)
+    (stats,) = side_statistics((jnp.asarray(F),))
     for it in range(5):
         h = prior.sample_hyper(jax.random.PRNGKey(it), jnp.asarray(U), h,
-                               side=jnp.asarray(F))
+                               jnp.asarray(F), stats)
     np.testing.assert_allclose(np.asarray(h["beta"]), beta_true,
                                rtol=0.15, atol=0.15)
 
